@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import sys
 import time
+import traceback
 
 MODULES = [
     "workflow_steps",
@@ -115,19 +116,27 @@ def main() -> None:
         raise SystemExit(smoke())
     names = sys.argv[1:] or MODULES
     print("name,us_per_call,derived")
+    failed = []
     for name in names:
         mod = importlib.import_module(f"benchmarks.{name}")
         t0 = time.perf_counter()
         try:
             rows = mod.run()
-        except Exception as e:  # noqa: BLE001 - report and continue
+        except Exception as e:  # noqa: BLE001 - report, run the rest, fail
+            traceback.print_exc()
             print(f"{name}_FAILED,0,{e!r}")
+            failed.append(name)
             continue
         for r in rows:
             print(r)
         print(f"# {name} done in {time.perf_counter() - t0:.1f}s",
               file=sys.stderr)
+    if failed:
+        raise SystemExit(f"benchmark modules failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -41,14 +41,13 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import route as route_lib
-from repro.core.distance import sq_norms
+from repro.core.distance import PRECISION, sq_norms
 from repro.core.engine import tilescan
 from repro.core.engine.plan import SearchPlan
 from repro.core.index_build import DistributedIndex
 from repro.core.lookup import LookupTable
 from repro.core.sentinels import INVALID_ID, LEAF_SENTINEL, PAD_QUERY_LEAF
-from repro.distributed.compat import pcast_varying, shard_map
-from repro.distributed.meshutil import batch_axes, round_up
+from repro.distributed.meshutil import batch_axes, pcast_varying, round_up
 
 
 @jax.tree_util.register_pytree_node_class
@@ -250,7 +249,7 @@ def _point_major_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows, q_total,
         row_spec = P(axes, None)
         flat_spec = P(axes)
         rep = P()
-        best_d, best_i, pairs, overflow = shard_map(
+        best_d, best_i, pairs, overflow = jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(row_spec, flat_spec, flat_spec, rep, rep, rep),
@@ -357,7 +356,7 @@ def _query_routed_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows, q_total,
         row_spec = P(axes, None)
         flat_spec = P(axes)
         rep = P()
-        cand_d, cand_i, qids, pairs, overflow = shard_map(
+        cand_d, cand_i, qids, pairs, overflow = jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(row_spec, flat_spec, flat_spec, row_spec, rep, rep, rep),
@@ -396,7 +395,8 @@ def _build_adc_lut(lookup_vecs, codebooks, *, q_total: int, m: int,
     sub = lookup_vecs.astype(jnp.float32).reshape(q_total, m, dsub)
     cb = codebooks.astype(jnp.float32)
     cross = jnp.einsum(
-        "qmd,mcd->qmc", sub, cb, preferred_element_type=jnp.float32
+        "qmd,mcd->qmc", sub, cb, preferred_element_type=jnp.float32,
+        precision=PRECISION,
     )
     return (
         jnp.sum(sub * sub, axis=-1)[:, :, None]
@@ -490,7 +490,7 @@ def _scan_codes_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows, q_total,
         row_spec = P(axes, None)
         flat_spec = P(axes)
         rep = P()
-        best_d, best_i, pairs, overflow = shard_map(
+        best_d, best_i, pairs, overflow = jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(row_spec, flat_spec, flat_spec, rep, rep, rep),
@@ -504,13 +504,6 @@ def _scan_codes_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows, q_total,
         )
 
     return pipeline
-
-
-def _kernel_tile_p(block_rows: int) -> int | None:
-    """The autotuned ``plan.block_rows`` doubles as the fusedscan point
-    tile when it is lane-aligned; otherwise fall back to the kernel's own
-    default tiling (the ops layer pads the shard up regardless)."""
-    return block_rows if block_rows % 128 == 0 else None
 
 
 def _point_major_fused_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows,
@@ -544,7 +537,6 @@ def _point_major_fused_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows,
         vecs, leaves, ids = vecs[0], leaves[0], ids[0]
         best_d, best_i = fused_ops.fused_topk(
             vecs, leaves, ids, lk_vecs, lk_leaves, k=k, impl="pallas",
-            tile_p=_kernel_tile_p(block_rows),
         )
         pairs = jax.lax.psum(
             _leaf_pair_count(leaves, lk_leaves, n_leaves), axes
@@ -618,7 +610,7 @@ def _point_major_fused_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows,
         row_spec = P(axes, None)
         flat_spec = P(axes)
         rep = P()
-        best_d, best_i, pairs, overflow = shard_map(
+        best_d, best_i, pairs, overflow = jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(row_spec, flat_spec, flat_spec, rep, rep, rep),
@@ -663,7 +655,6 @@ def _scan_codes_fused_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows,
         best_d, best_i = fused_ops.fused_adc_topk(
             codes, plf_m, ids, lk_lut.reshape(q_total, m, n_centers),
             lk_leaves, k=r, impl="pallas",
-            tile_p=_kernel_tile_p(block_rows),
         )
         pairs = jax.lax.psum(
             _leaf_pair_count(plf_m, lk_leaves, n_leaves), axes
@@ -743,7 +734,7 @@ def _scan_codes_fused_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows,
         row_spec = P(axes, None)
         flat_spec = P(axes)
         rep = P()
-        best_d, best_i, pairs, overflow = shard_map(
+        best_d, best_i, pairs, overflow = jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(row_spec, flat_spec, flat_spec, rep, rep, rep),

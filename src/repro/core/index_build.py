@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import route as route_lib
-from repro.distributed.compat import shard_map
 from repro.core.tree import VocabTree, tree_assign
 from repro.distributed.meshutil import batch_axes, data_axis_size, round_up
 
@@ -143,7 +142,7 @@ def build_index_fn(
         vecs = vecs.reshape(n_shards, rows_per_shard, vecs.shape[-1])
         ids = ids.reshape(n_shards, rows_per_shard)
         tree_specs = jax.tree.map(lambda _: P(), tree)
-        out = shard_map(
+        out = jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(row_spec, flat_spec, tree_specs),

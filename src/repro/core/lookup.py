@@ -15,7 +15,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from repro.core.distance import sq_norms
+from repro.core.distance import PRECISION, sq_norms
 from repro.core.sentinels import PAD_QUERY_LEAF
 from repro.core.tree import VocabTree, tree_assign
 
@@ -73,7 +73,8 @@ def probe_leaves(tree: VocabTree, queries: jax.Array, probes: int) -> jax.Array:
     n_q = qf.shape[0]
     roots = tree.levels[0].astype(jnp.float32)
     d2 = sq_norms(roots)[None, :] - 2.0 * jnp.einsum(
-        "qd,md->qm", qf, roots, preferred_element_type=jnp.float32
+        "qd,md->qm", qf, roots, preferred_element_type=jnp.float32,
+        precision=PRECISION,
     )  # (Q, f0) — same partial distance tree_assign's nearest() uses
     greedy = jnp.argmin(d2, axis=1).astype(jnp.int32)
     neg, nodes = jax.lax.top_k(-d2, min(probes, roots.shape[0]))
@@ -85,7 +86,8 @@ def probe_leaves(tree: VocabTree, queries: jax.Array, probes: int) -> jax.Array:
         cn = jnp.sum(lf * lf, axis=-1)  # (nodes, f) — loop-invariant
         gathered = lf[nodes]  # (Q, B, f, d)
         d2 = cn[nodes] - 2.0 * jnp.einsum(
-            "qd,qbfd->qbf", qf, gathered, preferred_element_type=jnp.float32
+            "qd,qbfd->qbf", qf, gathered, preferred_element_type=jnp.float32,
+            precision=PRECISION,
         )
         cand = nodes[:, :, None] * f + jnp.arange(f, dtype=jnp.int32)
         neg, sel = jax.lax.top_k(-d2.reshape(n_q, -1), min(probes, cand[0].size))
@@ -95,7 +97,8 @@ def probe_leaves(tree: VocabTree, queries: jax.Array, probes: int) -> jax.Array:
         # the hierarchy) — replace the worst slot when missing
         g_children = lf[greedy]  # (Q, f, d)
         gd2 = cn[greedy] - 2.0 * jnp.einsum(
-            "qd,qfd->qf", qf, g_children, preferred_element_type=jnp.float32
+            "qd,qfd->qf", qf, g_children, preferred_element_type=jnp.float32,
+            precision=PRECISION,
         )
         greedy = greedy * f + jnp.argmin(gd2, axis=1).astype(jnp.int32)
         has = (nodes == greedy[:, None]).any(axis=1)

@@ -25,7 +25,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.core.distance import nearest, sq_norms
+from repro.core.distance import PRECISION, nearest, sq_norms
 
 
 @jax.tree_util.register_pytree_node_class
@@ -115,7 +115,8 @@ def build_tree(
                 sq_norms(gathered)
                 - 2.0
                 * jnp.einsum("nd,nfd->nf", vf, gathered,
-                             preferred_element_type=jnp.float32)
+                             preferred_element_type=jnp.float32,
+                             precision=PRECISION)
             )
             branch = jnp.argmin(d2, axis=1).astype(jnp.int32)
         node_of = node_of * f + branch
@@ -142,7 +143,8 @@ def build_tree(
                     sq_norms(gathered)
                     - 2.0
                     * jnp.einsum("nd,nfd->nf", vf, gathered,
-                                 preferred_element_type=jnp.float32)
+                                 preferred_element_type=jnp.float32,
+                                 precision=PRECISION)
                 )
                 node_of = parent * f + jnp.argmin(d2, axis=1).astype(jnp.int32)
 
@@ -185,7 +187,8 @@ def tree_assign(tree: VocabTree, x: jax.Array) -> jax.Array:
         )  # (nodes, f)
         gathered = lvl[node]  # (n, f, d)
         d2 = cn[node] - 2.0 * jnp.einsum(
-            "nd,nfd->nf", xf, gathered, preferred_element_type=jnp.float32
+            "nd,nfd->nf", xf, gathered, preferred_element_type=jnp.float32,
+            precision=PRECISION,
         )
         node = node * f + jnp.argmin(d2, axis=1).astype(jnp.int32)
     return node

@@ -17,7 +17,7 @@ import math
 from typing import Sequence
 
 import jax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 
 def local_mesh(axes: Sequence[str] = ("data", "model")) -> Mesh:
@@ -25,22 +25,38 @@ def local_mesh(axes: Sequence[str] = ("data", "model")) -> Mesh:
     n = len(jax.devices())
     shape = [1] * len(axes)
     shape[0] = n
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def abstract_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """``AbstractMesh`` across jax versions.
-
-    Newer jax takes ``(sizes, names)``; 0.4.x takes a tuple of
-    ``(name, size)`` pairs. Tests and dry-runs use this so they never need
-    real devices.
-    """
+    """``AbstractMesh`` of the given sizes: tests and dry-runs use this so
+    they never need real devices."""
     from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(zip(tuple(axes), tuple(shape))))
+    return AbstractMesh(tuple(shape), tuple(axes))
+
+
+def pcast_varying(x, axes):
+    """Mark ``x`` as varying over ``axes`` inside shard_map.
+
+    A replicated value that becomes per-shard state (a loop carry, say)
+    needs the cast; axes ``x`` already varies over are left alone, since
+    ``pcast`` refuses a varying-to-varying cast.
+    """
+    missing = tuple(a for a in axes if a not in jax.typeof(x).vma)
+    return jax.lax.pcast(x, missing, to="varying")
+
+
+def match_varying(*xs):
+    """Cast ``xs`` to vary over the union of their manual mesh axes.
+
+    Returns ``(xs, vma)``. Inside shard_map a Pallas kernel's operands
+    must agree on the axes they vary over, and its outputs carry ``vma``;
+    outside shard_map ``vma`` is empty and ``xs`` come back unchanged.
+    """
+    vma = frozenset().union(*(jax.typeof(x).vma for x in xs))
+    return tuple(pcast_varying(x, tuple(sorted(vma))) for x in xs), vma
 
 
 def mesh_axis_size(mesh: Mesh, name: str) -> int:

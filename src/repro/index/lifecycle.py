@@ -333,8 +333,6 @@ class Index:
           FileNotFoundError: no committed manifest (including the
             pre-segment legacy ``index_ckpt/`` format, reported
             actionably).
-          ValueError: the committed segments were built for a different
-            device-shard count than ``mesh`` provides.
         """
         m = manifest_lib.latest(directory)
         if m is None:
@@ -349,15 +347,8 @@ class Index:
         mesh = mesh if mesh is not None else local_mesh()
         tree, tree_meta = _load_tree(directory, mesh)
         seg_dir = os.path.join(directory, manifest_lib.SEGMENTS_SUBDIR)
+        # segments built on another device count are re-cut for this mesh
         segments = [Segment.load(seg_dir, name, mesh) for name in m.segments]
-        want = data_axis_size(mesh)
-        for seg in segments:
-            if seg.n_shards != want:
-                raise ValueError(
-                    f"index segment {seg.name} was built for "
-                    f"{seg.n_shards} shards; current mesh has {want} — "
-                    "rebuild the index for this mesh"
-                )
         wire = jnp.dtype(tree_meta.get("wire_dtype", "float32"))
         quantizer, codes, codes_paths = None, {}, {}
         if m.codes:

@@ -15,13 +15,15 @@ import dataclasses
 import os
 import re
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.index_build import DistributedIndex
+from repro.core.sentinels import LEAF_SENTINEL
 from repro.distributed.checkpoint import CheckpointManager
-from repro.distributed.meshutil import batch_axes
+from repro.distributed.meshutil import batch_axes, data_axis_size, round_up
 
 _SEGMENT_RE = re.compile(r"^seg_(\d{6})$")
 
@@ -55,6 +57,50 @@ def _index_shardings(mesh: Mesh):
             overflow=rep,
         )
     }
+
+
+def place_on(index: DistributedIndex, mesh: Mesh) -> DistributedIndex:
+    """``index``'s rows laid out for ``mesh``'s shard count, on ``mesh``.
+
+    Each shard of a built index holds one contiguous leaf range, sorted,
+    so its real rows in shard order are leaf-sorted globally. Cutting
+    them at the new shards' leaf boundaries (and padding each shard to a
+    common row count) gives the layout a build on ``mesh`` would hold,
+    with every leaf's rows in the same order.
+    """
+    n_shards = data_axis_size(mesh)
+    if index.offsets.shape[0] != n_shards:
+        leaves = np.asarray(index.leaves)
+        real = leaves != LEAF_SENTINEL
+        leaves = leaves[real]
+        vecs = np.asarray(index.vecs)[real]
+        ids = np.asarray(index.ids)[real]
+        lps = index.n_leaves // n_shards
+        cuts = np.searchsorted(leaves, np.arange(n_shards + 1) * lps)
+        rows = round_up(max(int(np.diff(cuts).max()), 1), 8)
+        out_v = np.zeros((n_shards, rows, vecs.shape[1]), vecs.dtype)
+        out_i = np.full((n_shards, rows), -1, np.int32)
+        out_l = np.full((n_shards, rows), LEAF_SENTINEL, np.int32)
+        offsets = np.empty((n_shards, lps + 1), np.int32)
+        for s in range(n_shards):
+            a, b = cuts[s], cuts[s + 1]
+            out_v[s, :b - a] = vecs[a:b]
+            out_i[s, :b - a] = ids[a:b]
+            out_l[s, :b - a] = leaves[a:b]
+            offsets[s] = np.searchsorted(leaves[a:b] - s * lps,
+                                         np.arange(lps + 1))
+        index = DistributedIndex(
+            vecs=out_v.reshape(n_shards * rows, -1),
+            ids=out_i.reshape(-1),
+            leaves=out_l.reshape(-1),
+            offsets=offsets,
+            n_valid=np.diff(cuts).astype(np.int32),
+            overflow=index.overflow,
+            n_leaves=index.n_leaves,
+        )
+    shardings = dataclasses.replace(_index_shardings(mesh)["index"],
+                                    n_leaves=index.n_leaves)
+    return jax.device_put(index, shardings)
 
 
 @dataclasses.dataclass
@@ -182,7 +228,7 @@ class Segment:
         tree_out, _ = mgr.restore(skeleton, step,
                                   shardings=_index_shardings(mesh))
         index = tree_out["index"]
-        index = DistributedIndex(
+        index = place_on(DistributedIndex(
             vecs=index.vecs,
             ids=jnp.asarray(index.ids, jnp.int32),
             leaves=jnp.asarray(index.leaves, jnp.int32),
@@ -190,11 +236,11 @@ class Segment:
             n_valid=jnp.asarray(index.n_valid, jnp.int32),
             overflow=jnp.asarray(index.overflow, jnp.int32),
             n_leaves=int(meta["n_leaves"]),
-        )
+        ), mesh)
         return cls(
             name=name,
             index=index,
-            rows=int(meta["rows"]),
+            rows=int(index.rows),
             valid_rows=int(meta["valid_rows"]),
             min_id=int(meta.get("min_id", -1)),
             max_id=int(meta.get("max_id", -1)),

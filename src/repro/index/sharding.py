@@ -34,8 +34,10 @@ from __future__ import annotations
 import dataclasses
 from typing import Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.codes import rerank_exact
 from repro.core.engine import (
@@ -49,7 +51,7 @@ from repro.core.engine import (
 from repro.core.engine.executors import SearchResult
 from repro.core.search import jit_build_lookup, search_with_lookup
 from repro.distributed.meshutil import data_axis_size, shard_submeshes
-from repro.index.segment import dead_counts
+from repro.index.segment import dead_counts, place_on
 from repro.obs import get_registry
 
 STRATEGIES = ("round_robin", "balanced", "explicit")
@@ -378,6 +380,7 @@ class ShardedIndex:
             )
         self.plan = plan
         self._meshes = shard_submeshes(index.mesh, plan.n_shards)
+        self._placed_views: dict = {}
 
     @property
     def n_shards(self) -> int:
@@ -418,7 +421,8 @@ class ShardedIndex:
         """Per shard: ``(global_ordinal, masked DistributedIndex view)``
         pairs in global append order. Views are the index's cached
         tombstone-masked views — refreshed automatically after
-        append/delete/compact on the underlying index."""
+        append/delete/compact on the underlying index — placed on the
+        shard's own devices where it has a submesh."""
         by_name = {
             s.name: (g, v)
             for g, (s, v) in enumerate(
@@ -426,8 +430,30 @@ class ShardedIndex:
             )
         }
         return [
-            [by_name[name] for name in shard] for shard in self.plan.assignment
+            [(g, self._placed(si, g, v))
+             for g, v in (by_name[name] for name in shard)]
+            for si, shard in enumerate(self.plan.assignment)
         ]
+
+    def _placed(self, si: int, g: int, view):
+        """``view`` re-cut onto shard ``si``'s submesh (cached per view:
+        placing moves the segment's rows between devices)."""
+        mesh = self._meshes[si]
+        if mesh == self.index.mesh:
+            return view
+        hit = self._placed_views.get((si, g))
+        if hit is None or hit[0] is not view:
+            hit = (view, place_on(view, mesh))
+            self._placed_views[(si, g)] = hit
+        return hit[1]
+
+    def replicated(self, si: int, x):
+        """``x`` (a pytree the scatter legs share, e.g. the tree or a
+        lookup table) replicated over shard ``si``'s devices."""
+        mesh = self._meshes[si]
+        if mesh == self.index.mesh:
+            return x
+        return jax.device_put(x, NamedSharding(mesh, PartitionSpec()))
 
     def stats(self) -> dict:
         segs = {s.name: s for s in self.segments}
@@ -536,10 +562,13 @@ class ShardedIndex:
         pairs = overflow = 0
         pruned = 0
         live = self._live_counts()
-        for shard, mesh, scale in zip(views, self._meshes, scales):
+        for si, (shard, mesh, scale) in enumerate(
+            zip(views, self._meshes, scales)
+        ):
             if not shard:
                 continue  # more shards than segments: an empty scatter leg
             n_shards = data_axis_size(mesh)
+            shard_lookup = self.replicated(si, lookup)
             per_seg, ordinals = [], []
             for g, view in shard:
                 if live[g] == 0:
@@ -574,9 +603,9 @@ class ShardedIndex:
                         p, scale, n_queries=q,
                         shard_rows=view.rows // n_shards,
                     )
-                per_seg.append(
-                    search_with_lookup(view, lookup, p, mesh, n_queries=q)
-                )
+                per_seg.append(search_with_lookup(
+                    view, shard_lookup, p, mesh, n_queries=q
+                ))
                 ordinals.append(g)
             if not per_seg:
                 continue  # every segment of this shard was pruned
@@ -624,10 +653,13 @@ class ShardedIndex:
         pruned = 0
         live = self._live_counts()
         segs = self.segments
-        for shard, mesh, scale in zip(views, self._meshes, scales):
+        for si, (shard, mesh, scale) in enumerate(
+            zip(views, self._meshes, scales)
+        ):
             if not shard:
                 continue
             n_shards = data_axis_size(mesh)
+            shard_lookup = self.replicated(si, lookup)
             entries = []
             for g, view in shard:
                 if live[g] == 0:
@@ -650,7 +682,7 @@ class ShardedIndex:
                         shard_rows=view.rows // n_shards,
                     )
                 res = search_with_lookup(
-                    view, lookup, p, mesh, n_queries=q,
+                    view, shard_lookup, p, mesh, n_queries=q,
                     codes=self._codes_for(segs[g].name),
                     codebooks=pq.codebooks,
                 )

@@ -13,8 +13,9 @@ TPU mapping notes:
     — a (TQ, C) x (C, TP) dot per subspace, which beats a per-element
     VPU gather on TPU and needs no scatter/gather addressing.
   * reductions run along the lane (last) axis of a (TQ, TP) layout.
-  * top-k is k rounds of min-extraction + replace-current-max insertion,
-    identical to l2topk (k here is the *rerank depth*, kept <= 128).
+  * top-k is k rounds of min-extraction + replace-current-max insertion
+    (k here is the *rerank depth*, kept <= 128); unlike l2topk, ties keep
+    the reference's (distance, row) order, since PQ codes repeat.
   * grid = (q_tiles, p_tiles), p innermost ("arbitrary") so scratch
     carries across code tiles; q tiles are parallel.
 """
@@ -28,7 +29,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.distributed.compat import tpu_compiler_params as _tpu_compiler_params
+from repro.core.distance import PRECISION
+from repro.distributed.meshutil import match_varying
+
+
+_NO_ROW = 2**31 - 1  # above every row index
 
 
 def _extract_min(d2, iota, bound):
@@ -62,6 +67,7 @@ def adcscan_kernel(
         d2 = d2 + jax.lax.dot_general(
             lut[:, s * n_centers:(s + 1) * n_centers], onehot,
             (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=PRECISION,
         )  # (TQ, TP)
     match = qlf_ref[...] == plf_ref[...]  # (TQ,1) == (1,TP) -> (TQ, TP)
     d2 = jnp.where(match, d2, jnp.inf)
@@ -73,9 +79,15 @@ def adcscan_kernel(
     for _ in range(k):
         mv, a = _extract_min(d2, p_iota, tp)  # (TQ,1) tile-best
         d2 = jnp.where(p_iota == a, jnp.inf, d2)  # remove from tile
+        # evict the latest row among the tied maxima, and admit only a
+        # strictly smaller distance (a tied candidate is a later row): the
+        # table holds the k smallest by (distance, row), the reference's
+        # top_k order — PQ codes repeat, so ADC ties are common
         cur_max = jnp.max(rd, axis=1, keepdims=True)
         is_max = rd == cur_max
-        amax = jnp.min(jnp.where(is_max, k_iota, k), axis=1, keepdims=True)
+        max_row = jnp.max(jnp.where(is_max, ri, -1), axis=1, keepdims=True)
+        amax = jnp.min(jnp.where(is_max & (ri == max_row), k_iota, k),
+                       axis=1, keepdims=True)
         repl = (k_iota == amax) & (mv < cur_max)
         rd = jnp.where(repl, mv, rd)
         ri = jnp.where(repl, a + j * tp, ri)
@@ -88,12 +100,14 @@ def adcscan_kernel(
         ri2 = run_i[...]
         cols_d, cols_i = [], []
         for _ in range(k):
-            mv, am = _extract_min(rd2, k_iota, k)
-            sel = k_iota == am
-            ci = jnp.sum(jnp.where(sel, ri2, 0), axis=1, keepdims=True)
-            rd2 = jnp.where(sel, jnp.inf, rd2)
+            # ascending (distance, row); rows are unique among finite
+            # entries, and inf entries all emit -1
+            mv = jnp.min(rd2, axis=1, keepdims=True)
+            row = jnp.min(jnp.where(rd2 == mv, ri2, _NO_ROW), axis=1,
+                          keepdims=True)
+            rd2 = jnp.where((rd2 == mv) & (ri2 == row), jnp.inf, rd2)
             cols_d.append(mv)
-            cols_i.append(jnp.where(jnp.isfinite(mv), ci, jnp.int32(-1)))
+            cols_i.append(jnp.where(jnp.isfinite(mv), row, jnp.int32(-1)))
         out_d_ref[...] = jnp.concatenate(cols_d, axis=1)
         out_i_ref[...] = jnp.concatenate(cols_i, axis=1)
 
@@ -117,6 +131,9 @@ def adcscan_pallas(
     if P % tile_p or Q % tile_q:
         raise ValueError(f"{P=} % {tile_p=} or {Q=} % {tile_q=} nonzero")
     grid = (Q // tile_q, P // tile_p)
+    (lut, query_leaves, codes, point_leaves), vma = match_varying(
+        lut, query_leaves, codes, point_leaves
+    )
     kernel = functools.partial(adcscan_kernel, k=k, m=m, n_centers=n_centers)
     out_d, out_i = pl.pallas_call(
         kernel,
@@ -132,14 +149,14 @@ def adcscan_pallas(
             pl.BlockSpec((tile_q, k), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Q, k), jnp.float32),
-            jax.ShapeDtypeStruct((Q, k), jnp.int32),
+            jax.ShapeDtypeStruct((Q, k), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((Q, k), jnp.int32, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((tile_q, k), jnp.float32),
             pltpu.VMEM((tile_q, k), jnp.int32),
         ],
-        compiler_params=_tpu_compiler_params()(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

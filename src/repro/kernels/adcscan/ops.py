@@ -15,9 +15,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.sentinels import PAD_TILE_POINT_LEAF, PAD_TILE_QUERY_LEAF
+from repro.kernels import interpret_mode
 from repro.kernels.adcscan.kernel import adcscan_pallas
 from repro.kernels.adcscan.ref import adc_topk_ref
 from repro.kernels.l2topk.ops import resolve_impl
+from repro.kernels.tiles import adc_tiles
 
 # Probe-aware padding, same scheme as l2topk: point-side and query-side
 # tile padding use distinct negative sentinels so padded rows never match
@@ -51,8 +53,8 @@ def adc_topk(
 
     P, m = codes.shape
     Q, _, n_centers = lut.shape
-    tp = tile_p or min(512, _round_up(P, 128))
-    tq = tile_q or min(256, _round_up(Q, 128))
+    tp, tq = adc_tiles(P, Q, k=k, m=m, n_centers=n_centers)
+    tp, tq = tile_p or tp, tile_q or tq
     Pp, Qp = _round_up(P, tp), _round_up(Q, tq)
     cds = jnp.zeros((Pp, m), jnp.int32).at[:P].set(codes.astype(jnp.int32))
     lt = jnp.zeros((Qp, m * n_centers), jnp.float32).at[:Q].set(
@@ -73,6 +75,6 @@ def adc_topk(
         n_centers=n_centers,
         tile_p=tp,
         tile_q=tq,
-        interpret=jax.default_backend() != "tpu",
+        interpret=interpret_mode(),
     )
     return out_d[:Q], out_i[:Q]

@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.distributed.compat import tpu_compiler_params as _tpu_compiler_params
-
 
 def flashattn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                      scale: float, window: int, q_offset: int):
@@ -114,7 +112,7 @@ def flashattn_pallas(
             pltpu.VMEM((tile_q, 1), jnp.float32),
             pltpu.VMEM((tile_q, hd), jnp.float32),
         ],
-        compiler_params=_tpu_compiler_params()(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -7,6 +7,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.flashattn.kernel import flashattn_pallas
 from repro.kernels.flashattn.ref import flash_attention_ref
 
@@ -49,6 +50,6 @@ def flash_attention(
     vf = v.transpose(0, 2, 1, 3).reshape(B * Hkv, Skv, hd)
     out = flashattn_pallas(
         qf, kf, vf, group=group, window=window, tile_q=tq, tile_kv=tkv,
-        interpret=jax.default_backend() != "tpu",
+        interpret=interpret_mode(),
     )
     return out.reshape(B, Hq, Sq, hd).transpose(0, 2, 1, 3)

@@ -41,7 +41,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.distributed.compat import tpu_compiler_params as _tpu_compiler_params
+from repro.core.distance import PRECISION
+from repro.distributed.meshutil import match_varying
 
 
 def _extract_min(d2, iota, bound):
@@ -93,14 +94,15 @@ def _merge_sorted(run_d, run_i, cand_d, cand_i, *, k: int):
     return jnp.concatenate(cols_d, axis=1), jnp.concatenate(cols_i, axis=1)
 
 
-def _select_and_carry(d2, qlf, plf, out_d_ref, out_i_ref, run_d, run_i,
-                      *, k: int):
+def _select_and_carry(distances, qlf, plf, out_d_ref, out_i_ref, run_d,
+                      run_i, *, k: int):
     """The shared tail of both fused kernels: leaf-mask the distance
-    tile, fold its sorted top-k into the VMEM run table, emit at the
-    last point tile (leaf-disjoint tiles skip the fold entirely)."""
+    tile ``distances()``, fold its sorted top-k into the VMEM run table,
+    emit at the last point tile (leaf-disjoint tiles skip the distance
+    GEMM and the fold entirely)."""
     j = pl.program_id(1)
     np_tiles = pl.num_programs(1)
-    tq = d2.shape[0]
+    tq = run_d.shape[0]
 
     @pl.when(j == 0)
     def _init():
@@ -118,7 +120,7 @@ def _select_and_carry(d2, qlf, plf, out_d_ref, out_i_ref, run_d, run_i,
     @pl.when(overlap)
     def _fold():
         match = qlf[:, None] == plf[None, :]  # (TQ, TP)
-        masked = jnp.where(match, d2, jnp.inf)
+        masked = jnp.where(match, distances(), jnp.inf)
         tile_d, tile_i = _tile_topk_sorted(
             masked, k=k, row_base=j * plf.shape[0]
         )
@@ -135,46 +137,64 @@ def _select_and_carry(d2, qlf, plf, out_d_ref, out_i_ref, run_d, run_i,
                                    jnp.int32(-1))
 
 
-def fusedscan_kernel(q_ref, qlf_ref, p_ref, plf_ref, out_d_ref, out_i_ref,
-                     run_d, run_i, *, k: int):
-    pf = p_ref[...].astype(jnp.float32)
-    qf = q_ref[...].astype(jnp.float32)
-    # reference-identical partial distance: ||p||^2 - 2 q.p, contraction
-    # over d (NOT the augmented d+1 trick — it can round differently)
-    pn = jnp.sum(pf * pf, axis=1)  # (TP,)
-    d2 = pn[None, :] - 2.0 * jax.lax.dot_general(
-        qf, pf, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (TQ, TP)
-    _select_and_carry(d2, qlf_ref[...][:, 0], plf_ref[...][0, :],
+def fusedscan_kernel(q_ref, qlf_ref, p_ref, plf_ref, pn_ref, out_d_ref,
+                     out_i_ref, run_d, run_i, *, k: int):
+    def distances():
+        pf = p_ref[...].astype(jnp.float32)
+        qf = q_ref[...].astype(jnp.float32)
+        # reference-identical partial distance: ||p||^2 - 2 q.p,
+        # contraction over d (NOT the augmented d+1 trick — it can round
+        # differently). The point norms arrive precomputed as a
+        # lane-major (1, TP) row: a norm reduced here comes out
+        # sublane-major, and turning it into a row costs the compiler
+        # ~512 B of VMEM per (query, point) tile pair.
+        return pn_ref[...] - 2.0 * jax.lax.dot_general(
+            qf, pf, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=PRECISION,
+        )  # (TQ, TP)
+
+    _select_and_carry(distances, qlf_ref[...][:, 0], plf_ref[...][0, :],
                       out_d_ref, out_i_ref, run_d, run_i, k=k)
 
 
 def fusedadc_kernel(lut_ref, qlf_ref, codes_ref, plf_ref, out_d_ref,
                     out_i_ref, run_d, run_i, *, k: int, m: int,
                     n_centers: int):
-    lut = lut_ref[...]  # (TQ, m * C)
-    codes = codes_ref[...]  # (TP, m) int32
-    tq = lut.shape[0]
-    tp = codes.shape[0]
-    c_iota = jax.lax.broadcasted_iota(jnp.int32, (tp, n_centers), 1)
-    d2 = jnp.zeros((tq, tp), jnp.float32)
-    for s in range(m):
-        onehot = (c_iota == codes[:, s][:, None]).astype(jnp.float32)
-        d2 = d2 + jax.lax.dot_general(
-            lut[:, s * n_centers:(s + 1) * n_centers], onehot,
-            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-        )  # (TQ, TP)
-    _select_and_carry(d2, qlf_ref[...][:, 0], plf_ref[...][0, :],
+    def distances():
+        lut = lut_ref[...]  # (TQ, m * C)
+        codes = codes_ref[...]  # (TP, m) int32
+        tq = lut.shape[0]
+        tp = codes.shape[0]
+        c_iota = jax.lax.broadcasted_iota(jnp.int32, (tp, n_centers), 1)
+        d2 = jnp.zeros((tq, tp), jnp.float32)
+        for s in range(m):
+            onehot = (c_iota == codes[:, s][:, None]).astype(jnp.float32)
+            d2 = d2 + jax.lax.dot_general(
+                lut[:, s * n_centers:(s + 1) * n_centers], onehot,
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=PRECISION,
+            )  # (TQ, TP)
+        return d2
+
+    _select_and_carry(distances, qlf_ref[...][:, 0], plf_ref[...][0, :],
                       out_d_ref, out_i_ref, run_d, run_i, k=k)
 
 
-def _pallas_scan(kernel, q_side, qlf, p_side, plf, *, k, tile_p, tile_q,
+def _pallas_scan(kernel, q_side, qlf, p_side, p_rows, *, k, tile_p, tile_q,
                  interpret):
+    """Launch ``kernel`` over the (query tile, point tile) grid.
+
+    ``p_rows`` are the per-point ``(1, P)`` rows the kernel reads beside
+    ``p_side`` (the leaves first), each blocked ``(1, tile_p)``.
+    """
     P = p_side.shape[0]
     Q = q_side.shape[0]
     if P % tile_p or Q % tile_q:
         raise ValueError(f"{P=} % {tile_p=} or {Q=} % {tile_q=} nonzero")
     grid = (Q // tile_q, P // tile_p)
+    (q_side, qlf, p_side, *p_rows), vma = match_varying(
+        q_side, qlf, p_side, *p_rows
+    )
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -182,25 +202,24 @@ def _pallas_scan(kernel, q_side, qlf, p_side, plf, *, k, tile_p, tile_q,
             pl.BlockSpec((tile_q, q_side.shape[1]), lambda i, j: (i, 0)),
             pl.BlockSpec((tile_q, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((tile_p, p_side.shape[1]), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, tile_p), lambda i, j: (0, j)),
-        ],
+        ] + [pl.BlockSpec((1, tile_p), lambda i, j: (0, j))] * len(p_rows),
         out_specs=[
             pl.BlockSpec((tile_q, k), lambda i, j: (i, 0)),
             pl.BlockSpec((tile_q, k), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Q, k), jnp.float32),
-            jax.ShapeDtypeStruct((Q, k), jnp.int32),
+            jax.ShapeDtypeStruct((Q, k), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((Q, k), jnp.int32, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((tile_q, k), jnp.float32),
             pltpu.VMEM((tile_q, k), jnp.int32),
         ],
-        compiler_params=_tpu_compiler_params()(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(q_side, qlf, p_side, plf)
+    )(q_side, qlf, p_side, *p_rows)
 
 
 def fusedscan_pallas(
@@ -210,14 +229,16 @@ def fusedscan_pallas(
     query_leaves: jax.Array,  # (Q, 1) int32
     *,
     k: int,
-    tile_p: int = 512,
-    tile_q: int = 256,
-    interpret: bool = False,
+    tile_p: int,
+    tile_q: int,
+    interpret=False,
 ):
+    pf = points.astype(jnp.float32)
+    norms = jnp.sum(pf * pf, axis=1)[None, :]  # (1, P), as the reference
     kernel = functools.partial(fusedscan_kernel, k=k)
-    return _pallas_scan(kernel, queries, query_leaves, points, point_leaves,
-                        k=k, tile_p=tile_p, tile_q=tile_q,
-                        interpret=interpret)
+    return _pallas_scan(kernel, queries, query_leaves, points,
+                        (point_leaves, norms), k=k, tile_p=tile_p,
+                        tile_q=tile_q, interpret=interpret)
 
 
 def fusedadc_pallas(
@@ -228,14 +249,14 @@ def fusedadc_pallas(
     *,
     k: int,
     n_centers: int,
-    tile_p: int = 512,
-    tile_q: int = 256,
-    interpret: bool = False,
+    tile_p: int,
+    tile_q: int,
+    interpret=False,
 ):
     m = codes.shape[1]
     if lut.shape[1] != m * n_centers:
         raise ValueError(f"lut width {lut.shape[1]} != {m=} * {n_centers=}")
     kernel = functools.partial(fusedadc_kernel, k=k, m=m, n_centers=n_centers)
-    return _pallas_scan(kernel, lut, query_leaves, codes, point_leaves,
+    return _pallas_scan(kernel, lut, query_leaves, codes, (point_leaves,),
                         k=k, tile_p=tile_p, tile_q=tile_q,
                         interpret=interpret)

@@ -26,9 +26,11 @@ from repro.core.sentinels import (
     PAD_TILE_POINT_LEAF,
     PAD_TILE_QUERY_LEAF,
 )
+from repro.kernels import interpret_mode
 from repro.kernels.fusedscan.kernel import fusedadc_pallas, fusedscan_pallas
 from repro.kernels.fusedscan.ref import fused_adc_topk_ref, fused_topk_ref
 from repro.kernels.l2topk.ops import resolve_impl
+from repro.kernels.tiles import adc_tiles, dense_tiles
 
 _PAD_P_LEAF = PAD_TILE_POINT_LEAF
 _PAD_Q_LEAF = PAD_TILE_QUERY_LEAF
@@ -36,12 +38,6 @@ _PAD_Q_LEAF = PAD_TILE_QUERY_LEAF
 
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
-
-
-def _tiles(P: int, Q: int, tile_p, tile_q) -> tuple[int, int]:
-    tp = tile_p or min(512, _round_up(P, 128))
-    tq = tile_q or min(256, _round_up(Q, 128))
-    return tp, tq
 
 
 def _pad_leaves(leaves, n: int, pad_leaf: int):
@@ -79,7 +75,9 @@ def fused_topk(
 
     P, d = points.shape
     Q = queries.shape[0]
-    tp, tq = _tiles(P, Q, tile_p, tile_q)
+    tp, tq = dense_tiles(P, Q, k=k, d=d, itemsize=max(
+        points.dtype.itemsize, queries.dtype.itemsize))
+    tp, tq = tile_p or tp, tile_q or tq
     Pp, Qp = _round_up(P, tp), _round_up(Q, tq)
     pts = jnp.zeros((Pp, d), points.dtype).at[:P].set(points)
     qrs = jnp.zeros((Qp, d), queries.dtype).at[:Q].set(queries)
@@ -87,7 +85,7 @@ def fused_topk(
     qlf = _pad_leaves(query_leaves, Qp, _PAD_Q_LEAF)
     out_d, sel = fusedscan_pallas(
         pts, plf[None, :], qrs, qlf[:, None], k=k, tile_p=tp, tile_q=tq,
-        interpret=jax.default_backend() != "tpu",
+        interpret=interpret_mode(),
     )
     return _map_ids(out_d, sel, point_ids, Q)
 
@@ -115,7 +113,8 @@ def fused_adc_topk(
 
     P, m = codes.shape
     Q, _, n_centers = lut.shape
-    tp, tq = _tiles(P, Q, tile_p, tile_q)
+    tp, tq = adc_tiles(P, Q, k=k, m=m, n_centers=n_centers)
+    tp, tq = tile_p or tp, tile_q or tq
     Pp, Qp = _round_up(P, tp), _round_up(Q, tq)
     cds = jnp.zeros((Pp, m), jnp.int32).at[:P].set(codes.astype(jnp.int32))
     lt = jnp.zeros((Qp, m * n_centers), jnp.float32).at[:Q].set(
@@ -126,6 +125,6 @@ def fused_adc_topk(
     out_d, sel = fusedadc_pallas(
         cds, plf[None, :], lt, qlf[:, None], k=k, n_centers=n_centers,
         tile_p=tp, tile_q=tq,
-        interpret=jax.default_backend() != "tpu",
+        interpret=interpret_mode(),
     )
     return _map_ids(out_d, sel, point_ids, Q)

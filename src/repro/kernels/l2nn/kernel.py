@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.distributed.compat import tpu_compiler_params as _tpu_compiler_params
-
 
 def l2nn_kernel(x_ref, c_ref, out_i_ref, out_d_ref, best_d, best_i, *, n_valid_c: int):
     j = pl.program_id(1)
@@ -90,7 +88,7 @@ def l2nn_pallas(
             pltpu.VMEM((tile_n, 1), jnp.float32),
             pltpu.VMEM((tile_n, 1), jnp.int32),
         ],
-        compiler_params=_tpu_compiler_params()(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

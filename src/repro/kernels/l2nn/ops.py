@@ -7,6 +7,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.l2nn.kernel import l2nn_pallas
 from repro.kernels.l2nn.ref import l2_nearest_ref
 
@@ -50,7 +51,7 @@ def l2_nearest(
         cp,
         tile_n=tn,
         tile_c=tc,
-        interpret=jax.default_backend() != "tpu",
+        interpret=interpret_mode(),
         n_valid_c=C,
     )
     return out_i[:N, 0], out_d[:N, 0]

@@ -29,8 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.distributed.compat import tpu_compiler_params as _tpu_compiler_params
-
+from repro.core.distance import PRECISION
+from repro.distributed.meshutil import match_varying
 
 
 def _augment(q_tile, p_tile):
@@ -41,7 +41,8 @@ def _augment(q_tile, p_tile):
     pa = jnp.concatenate([pf, pn], axis=1)  # (TP, d+1)
     qa = jnp.concatenate([-2.0 * qf, jnp.ones_like(qf[:, :1])], axis=1)
     return jax.lax.dot_general(
-        qa, pa, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        qa, pa, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=PRECISION,
     )  # (TQ, TP)
 
 
@@ -118,6 +119,9 @@ def l2topk_pallas(
     if P % tile_p or Q % tile_q:
         raise ValueError(f"{P=} % {tile_p=} or {Q=} % {tile_q=} nonzero")
     grid = (Q // tile_q, P // tile_p)
+    (queries, query_leaves, points, point_leaves), vma = match_varying(
+        queries, query_leaves, points, point_leaves
+    )
     kernel = functools.partial(l2topk_kernel, k=k)
     out_d, out_i = pl.pallas_call(
         kernel,
@@ -133,14 +137,14 @@ def l2topk_pallas(
             pl.BlockSpec((tile_q, k), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Q, k), jnp.float32),
-            jax.ShapeDtypeStruct((Q, k), jnp.int32),
+            jax.ShapeDtypeStruct((Q, k), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((Q, k), jnp.int32, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((tile_q, k), jnp.float32),
             pltpu.VMEM((tile_q, k), jnp.int32),
         ],
-        compiler_params=_tpu_compiler_params()(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -14,8 +14,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.sentinels import PAD_TILE_POINT_LEAF, PAD_TILE_QUERY_LEAF
+from repro.kernels import interpret_mode
 from repro.kernels.l2topk.kernel import l2topk_pallas
 from repro.kernels.l2topk.ref import l2_topk_ref
+from repro.kernels.tiles import l2topk_tiles
 
 # Probe-aware padding: point-side and query-side tile padding use distinct
 # negative sentinels so padded rows never match anything — not real leaves,
@@ -55,8 +57,8 @@ def l2_topk(
 
     P, d = points.shape
     Q = queries.shape[0]
-    tp = tile_p or min(512, _round_up(P, 128))
-    tq = tile_q or min(256, _round_up(Q, 128))
+    tp, tq = l2topk_tiles(P, Q)
+    tp, tq = tile_p or tp, tile_q or tq
     Pp, Qp = _round_up(P, tp), _round_up(Q, tq)
     pts = jnp.zeros((Pp, d), points.dtype).at[:P].set(points)
     qrs = jnp.zeros((Qp, d), queries.dtype).at[:Q].set(queries)
@@ -74,6 +76,6 @@ def l2_topk(
         k=k,
         tile_p=tp,
         tile_q=tq,
-        interpret=jax.default_backend() != "tpu",
+        interpret=interpret_mode(),
     )
     return out_d[:Q], out_i[:Q]

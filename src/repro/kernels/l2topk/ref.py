@@ -18,13 +18,16 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.distance import PRECISION
+
 
 def l2_topk_ref(points, point_leaves, queries, query_leaves, k: int):
     pf = points.astype(jnp.float32)
     qf = queries.astype(jnp.float32)
     pn = jnp.sum(pf * pf, axis=-1)
     d2 = pn[:, None] - 2.0 * jnp.einsum(
-        "pd,qd->pq", pf, qf, preferred_element_type=jnp.float32
+        "pd,qd->pq", pf, qf, preferred_element_type=jnp.float32,
+        precision=PRECISION,
     )
     match = point_leaves[:, None] == query_leaves[None, :]
     d2 = jnp.where(match, d2, jnp.inf)

@@ -335,4 +335,7 @@ def _run(args, tracer) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     raise SystemExit(main())

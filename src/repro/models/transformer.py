@@ -367,9 +367,7 @@ def _moe_ffn_routed(x2d, layer, cfg: TransformerConfig, capacity: int):
         return out, drops
 
     dt = x2d.dtype
-    from repro.distributed.compat import shard_map
-
-    out, drops = shard_map(
+    out, drops = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(

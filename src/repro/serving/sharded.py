@@ -155,6 +155,10 @@ class ShardedSearchSession(SearchSession):
             codes=self._pin.codes or None, tombstones=self._pin.tombstones,
         )
         shard_views = self.sharded.shard_views()
+        self._shard_trees = [
+            self.sharded.replicated(si, self.tree)
+            for si in range(len(shard_views))
+        ]
         self._shard_codes = {}
         if self._use_codes:
             # device codes aligned with global segment ordinals; each
@@ -324,10 +328,11 @@ class ShardedSearchSession(SearchSession):
     def _dispatch_shard(self, si, rt, views, buf, n_valid):
         """Invoke one shard's fused pipeline (codes rungs take that
         shard's device codes + the codebook table as extra args)."""
+        tree = self._shard_trees[si]
         if rt.rerank is not None:
             return rt.fn(views, self._shard_codes[si],
-                         self._codebooks_dev, self.tree, buf, n_valid)
-        return rt.fn(views, self.tree, buf, n_valid)
+                         self._codebooks_dev, tree, buf, n_valid)
+        return rt.fn(views, tree, buf, n_valid)
 
     def _execute(
         self, queries: np.ndarray, *, n_images: int | None = None

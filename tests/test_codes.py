@@ -109,7 +109,9 @@ def test_pq_decode_reduces_error_and_lut_is_exact(corpus):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("shape", [(300, 40, 4, 16), (513, 129, 8, 256)])
+@pytest.mark.parametrize(
+    "shape", [(300, 40, 4, 16), (513, 129, 8, 256), (700, 64, 2, 4)]
+)
 def test_adcscan_kernel_matches_ref(shape):
     P, Q, m, C = shape
     rng = np.random.default_rng(3)
@@ -124,12 +126,9 @@ def test_adcscan_kernel_matches_ref(shape):
                       k=8, impl="pallas")
     np.testing.assert_allclose(np.asarray(kd), np.asarray(rd),
                                rtol=1e-5, atol=1e-4)
-    # ids must agree wherever the distance is unique (ties may reorder)
-    rd, kd, ri, ki = map(np.asarray, (rd, kd, ri, ki))
-    unique = np.ones_like(rd, bool)
-    unique[:, 1:] &= rd[:, 1:] != rd[:, :-1]
-    unique[:, :-1] &= rd[:, :-1] != rd[:, 1:]
-    np.testing.assert_array_equal(ri[unique], ki[unique])
+    # ids agree everywhere: ties keep the reference's (distance, row)
+    # order (the 2-subvector x 4-centroid case is almost all ties)
+    np.testing.assert_array_equal(np.asarray(ki), np.asarray(ri))
 
 
 # ---------------------------------------------------------------------------

@@ -137,3 +137,32 @@ def test_l2topk_property_sweep(p, q, k, n_leaves, seed):
             atol=1e-4,
         )
         assert set(got.tolist()) <= set(cand.tolist())
+
+
+@pytest.mark.parametrize(
+    "P,Q,k,d,dense,adc",
+    [(8_400_000, 4096, 20, 128, (1024, 256), (512, 128)),
+     (8_400_000, 4096, 128, 128, (2048, 128), (512, 128)),
+     (1000, 256, 20, 768, (512, 256), (512, 128)),
+     (300, 40, 4, 32, (256, 128), (256, 128))],
+)
+def test_kernel_tiles_fit_the_vmem_budget(P, Q, k, d, dense, adc):
+    """The largest lane-aligned tiles within the VMEM plan, never past
+    the lane-rounded operand."""
+    from repro.kernels import tiles
+
+    assert tiles.dense_tiles(P, Q, k=k, d=d, itemsize=4) == dense
+    assert tiles.adc_tiles(P, Q, k=k, m=8, n_centers=256) == adc
+    for tp, tq, row in ((*dense, dict(p_row_bytes=8 * d,
+                                      q_row_bytes=8 * d)),
+                        (*adc, dict(p_row_bytes=1024 + 8192,
+                                    q_row_bytes=16384))):
+        assert tiles.vmem_estimate(tp, tq, k=k, **row) <= tiles.VMEM_BUDGET
+
+
+def test_kernel_tiles_refuse_what_cannot_fit():
+    from repro.kernels import tiles
+
+    with pytest.raises(ValueError, match="VMEM"):
+        tiles.choose_tiles(4096, 4096, k=20, p_row_bytes=1 << 20,
+                           q_row_bytes=1 << 20)

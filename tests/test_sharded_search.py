@@ -98,7 +98,7 @@ def test_explicit_plan_cannot_rederive(corpus):
 # ---------------------------------------------------------------------------
 
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(
     n_segments=st.integers(1, 4),
     n_shards=st.integers(1, 4),
