@@ -45,11 +45,8 @@ def smoke() -> int:
     reopen → auto plans scan_codes → ADC + rerank recall floor at ≥8x
     fewer resident bytes), the fused-kernel gate (fused == xla on a
     served trace, zero recompiles, ms/image within 1.5x), and the
-    observability gate (traced ==
-    untraced bit-identity, valid Chrome trace + registry dump +
-    tracereport) —
-    the per-PR gate wired into scripts/smoke.sh. Fails loudly,
-    returns rc."""
+    dynamicity gate (serve while a writer appends and compacts) — the
+    per-PR gate wired into scripts/smoke.sh. Fails loudly, returns rc."""
     from benchmarks import indexing as indexing_bench
     from benchmarks import serving as serving_bench
     from repro.launch import serve
@@ -101,12 +98,7 @@ def smoke() -> int:
     print("# smoke: dynamicity (serve while a writer appends + "
           "incrementally compacts: 0 drops, 0 recompiles, bounded p95, "
           "final == fresh open)", file=sys.stderr)
-    rc = serving_bench.dynamicity_smoke()
-    if rc != 0:
-        return rc
-    print("# smoke: observability (traced == untraced bit-identity, "
-          "Chrome trace, registry, tracereport)", file=sys.stderr)
-    return serving_bench.obs_smoke()
+    return serving_bench.dynamicity_smoke()
 
 
 def main() -> None:

@@ -731,101 +731,6 @@ def sharded_smoke() -> int:
     return 0
 
 
-def obs_smoke() -> int:
-    """Observability gate. Asserts (a) a traced 2-shard replay returns
-    ids + distances bit-identical to the untraced replay of the same
-    trace (tracing must never perturb results), (b) the trace is
-    non-empty and carries the full span taxonomy with both shard lanes,
-    (c) the Chrome export round-trips as valid JSON with monotone
-    timestamps, (d) the registry dump is non-empty, and (e)
-    ``scripts/tracereport.py`` digests the trace into a top-N report."""
-    import subprocess
-    import sys
-    import tempfile
-
-    import numpy as np
-
-    from repro.index import Index
-    from repro.obs import Tracer, get_registry, tracing, write_chrome_trace
-    from repro.serving import (
-        MicroBatcher,
-        ShardedSearchSession,
-        TraceLoadGenerator,
-    )
-
-    c = Corpus(rows=20_000, dim=32, fanouts=(16, 16))
-    idx = Index.create(c.tree, None, mesh=c.mesh)
-    idx.append(c.vecs_np[:12_000])
-    idx.append(c.vecs_np[12_000:])
-    idx.commit()
-    dpi = 20
-    n_images = len(c.vecs_np) // dpi
-    gen = TraceLoadGenerator(c.vecs_np, dpi, seed=3)
-    reqs = gen.from_trace(80, n_images, skew="zipf", rate=200.0)
-
-    def replay(tracer):
-        # cache OFF: the virtual clock advances by measured wall compute,
-        # so cache admission timing can differ between replays, and a
-        # cache-served answer is a CPU recompute under a rounding contract
-        # — not the engine's bits. Engine-only replays are deterministic.
-        s = ShardedSearchSession(idx, mesh=c.mesh, shards=2, k=10,
-                                 buckets=(256, 1024), cache_leaves=0)
-        s.warmup()
-        with tracing(tracer):
-            comps = MicroBatcher(s, max_wait_ms=5.0).run(reqs)
-        return {cc.rid: cc for cc in comps if cc.ids is not None}, s
-
-    base, _ = replay(None)
-    tracer = Tracer(sample=1.0, seed=0)
-    # keep the traced session alive through the registry dump below — its
-    # ServingMetrics source is weakly held and would be pruned once GC'd
-    traced, session = replay(tracer)
-    assert set(base) == set(traced), "traced replay completed different rids"
-    for rid, cc in traced.items():
-        np.testing.assert_array_equal(cc.ids, base[rid].ids)
-        np.testing.assert_array_equal(cc.dists, base[rid].dists)
-    assert session.metrics.requests == len(reqs)
-    d = tracer.describe()
-    assert d["spans"] > 0, d
-    names = {s.name for s in tracer.spans}
-    for want in ("request", "queue.wait", "compute", "engine.dispatch",
-                 "shard.scan", "gather.merge"):
-        assert want in names, f"missing {want} spans (have {sorted(names)})"
-    shards_seen = {
-        s.attrs["shard"] for s in tracer.spans if s.name == "shard.scan"
-    }
-    assert shards_seen == {0, 1}, shards_seen
-    with tempfile.TemporaryDirectory() as td:
-        path = write_chrome_trace(tracer, os.path.join(td, "trace.json"))
-        with open(path) as f:
-            doc = json.load(f)
-        evs = [e for e in doc["traceEvents"] if e["ph"] != "M"]
-        assert evs, "empty Chrome trace"
-        ts = [e["ts"] for e in evs]
-        assert ts == sorted(ts), "Chrome trace timestamps not monotone"
-        with open(get_registry().dump(os.path.join(td, "m.json"))) as f:
-            snap = json.load(f)
-        assert snap["metrics"], "empty registry dump"
-        assert any(k.startswith("serving_metrics") for k in snap["sources"])
-        script = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "scripts", "tracereport.py",
-        )
-        rep = subprocess.run(
-            [sys.executable, script, path, "--top", "3"],
-            capture_output=True, text=True, timeout=120,
-        )
-        assert rep.returncode == 0, rep.stderr
-        assert "slowest" in rep.stdout, rep.stdout
-    print(
-        f"# obs smoke: traced == untraced on {len(base)} requests "
-        f"(2 shards); {d['spans']} spans / {d['events']} events; Chrome "
-        f"export valid + monotone; registry {len(snap['metrics'])} series; "
-        f"tracereport OK"
-    )
-    return 0
-
-
 def codes_smoke() -> int:
     """Compressed-codes gate: train → encode → commit → ``Index.open``
     round-trips the codebook → ``plan(model="auto")`` picks the
@@ -1074,10 +979,6 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="run the serving-session smoke gate")
-    ap.add_argument("--obs-smoke", action="store_true",
-                    help="run the observability gate (traced == untraced "
-                         "bit-identity, valid Chrome trace, registry dump, "
-                         "tracereport)")
     ap.add_argument("--sharded-smoke", action="store_true",
                     help="run the scatter-gather bit-identity gate")
     ap.add_argument("--calibration-smoke", action="store_true",
@@ -1138,8 +1039,6 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     if args.smoke:
         return smoke()
-    if args.obs_smoke:
-        return obs_smoke()
     if args.sharded_smoke:
         return sharded_smoke()
     if args.calibration_smoke:

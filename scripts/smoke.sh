@@ -34,10 +34,8 @@ python -m pytest -x -q
 # while a writer thread appends + incrementally compacts: 0 dropped
 # requests, 0 steady-state recompiles, p95 within 2x of a frozen baseline,
 # final results bit-identical to a fresh open; standalone:
-# benchmarks.serving --dynamicity-smoke), and the observability gate
-# (traced == untraced bit-identity at 2 shards, valid Chrome trace,
-# registry dump, tracereport; standalone: benchmarks.serving --obs-smoke)
-echo "== serve smoke (both layouts, --probes 2) + lifecycle + session + calibration + shard + SLO + codes + dynamicity + obs gates =="
+# benchmarks.serving --dynamicity-smoke)
+echo "== serve smoke (both layouts, --probes 2) + lifecycle + session + calibration + shard + SLO + codes + dynamicity gates =="
 python -m benchmarks.run --smoke
 
 echo "== serving CLI smoke (zipf trace, hot-leaf cache, recompile gate) =="
@@ -51,17 +49,10 @@ python -m repro.launch.serve --rows 20000 --dim 32 --images 400 \
     --fanout 16 16 --trace multi --requests 120 --target-p95-ms 150 \
     --rate 400 --no-recall
 
-echo "== sharded serving CLI smoke (scatter-gather, 2 shards, traced) =="
-OBS_TMP="$(mktemp -d)"
-trap 'rm -rf "$OBS_TMP"' EXIT
+echo "== sharded serving CLI smoke (scatter-gather, 2 shards) =="
 python -m repro.launch.serve --rows 20000 --dim 32 --images 400 \
     --fanout 16 16 --trace zipf --requests 100 --buckets 512 \
     --shards 2 --shard-plan balanced --cache-leaves 256 --cache-admit 1 \
-    --rate 300 --no-recall \
-    --trace-out "$OBS_TMP/serve_trace.json" \
-    --metrics-out "$OBS_TMP/serve_metrics.json"
-
-echo "== trace report (top-3 slowest from the traced CLI run) =="
-python scripts/tracereport.py "$OBS_TMP/serve_trace.json" --top 3
+    --rate 300 --no-recall
 
 echo "smoke OK"

@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core import phases
 from repro.core import route as route_lib
 from repro.core.distance import PRECISION, sq_norms
 from repro.core.engine import tilescan
@@ -102,6 +103,7 @@ def _fused_wants_kernel() -> bool:
     return jax.default_backend() == "tpu"
 
 
+@jax.named_scope(phases.COUNT)
 def _leaf_pair_count(p_leaves, q_leaves, n_leaves: int):
     """Analytic (point, query) leaf-collision count for the whole-shard
     kernel path: the kernel scans every (tile, tile) cell but only
@@ -119,6 +121,7 @@ def _leaf_pair_count(p_leaves, q_leaves, n_leaves: int):
     return jnp.sum(p_cnt * q_cnt)
 
 
+@jax.named_scope(phases.LOOKUP)
 def pad_lookup(lookup: LookupTable, q_total: int) -> LookupTable:
     """Pad the lookup table to ``q_total`` rows; padding never matches.
 
@@ -150,6 +153,7 @@ def _shard_id(mesh: Mesh, axes) -> jax.Array:
     return sid
 
 
+@jax.named_scope(phases.MERGE)
 def _merge_shard_tables(mesh, axes, plan, lookup, best_d, best_i, pairs,
                         overflow, *, q_total, n_shards, width, add_q_norms):
     """Merge per-shard ``(S, Q, width)`` k-NN tables into a SearchResult.
@@ -198,47 +202,51 @@ def _point_major_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows, q_total,
         vecs, leaves, ids = vecs[0], leaves[0], ids[0]
 
         def wave(i, c: _Carry) -> _Carry:
-            start = i * block_rows
-            pv = jax.lax.dynamic_slice(vecs, (start, 0), (block_rows, vecs.shape[1]))
-            plf = jax.lax.dynamic_slice(leaves, (start,), (block_rows,))
-            pid = jax.lax.dynamic_slice(ids, (start,), (block_rows,))
-            # contiguous query slab for this tile's leaf span
-            slab = tilescan.leaf_slab(
-                lk_offsets, plf[0], n_entries=n_leaves, total_rows=q_total,
-                cap=q_cap,
-            )
-            qv = jax.lax.dynamic_slice(
-                lk_vecs, (slab.start, 0), (q_cap, lk_vecs.shape[1])
-            )
-            qlf = jax.lax.dynamic_slice(lk_leaves, (slab.start,), (q_cap,))
+            with jax.named_scope(phases.SLICE):
+                start = i * block_rows
+                pv = jax.lax.dynamic_slice(
+                    vecs, (start, 0), (block_rows, vecs.shape[1])
+                )
+                plf = jax.lax.dynamic_slice(leaves, (start,), (block_rows,))
+                pid = jax.lax.dynamic_slice(ids, (start,), (block_rows,))
+                # contiguous query slab for this tile's leaf span
+                slab = tilescan.leaf_slab(
+                    lk_offsets, plf[0], n_entries=n_leaves,
+                    total_rows=q_total, cap=q_cap,
+                )
+                qv = jax.lax.dynamic_slice(
+                    lk_vecs, (slab.start, 0), (q_cap, lk_vecs.shape[1])
+                )
+                qlf = jax.lax.dynamic_slice(lk_leaves, (slab.start,), (q_cap,))
             cand_d, cand_i = tilescan.scan_tile(
                 pv, plf, pid, qv, qlf, k=k, impl=plan.impl
             )
             # fold into the running per-query k-NN table
-            cur_d = jax.lax.dynamic_slice(c.best_d, (slab.start, 0), (q_cap, k))
-            cur_i = jax.lax.dynamic_slice(c.best_i, (slab.start, 0), (q_cap, k))
-            new_d, new_i = tilescan.fold_topk(cur_d, cur_i, cand_d, cand_i)
-            best_d = jax.lax.dynamic_update_slice(c.best_d, new_d, (slab.start, 0))
-            best_i = jax.lax.dynamic_update_slice(c.best_i, new_i, (slab.start, 0))
-            # bookkeeping: pairs computed + slab-budget misses
-            pairs = c.pairs + tilescan.count_pairs(plf, qlf)
-            overflow = c.overflow + tilescan.slab_overflow(
-                lk_offsets, tilescan.last_valid_leaf(plf), slab,
-                n_entries=n_leaves,
+            best_d, best_i = tilescan.fold_rows(
+                c.best_d, c.best_i, cand_d, cand_i, slab.start
             )
+            # bookkeeping: pairs computed + slab-budget misses
+            with jax.named_scope(phases.COUNT):
+                pairs = c.pairs + tilescan.count_pairs(plf, qlf)
+                overflow = c.overflow + tilescan.slab_overflow(
+                    lk_offsets, tilescan.last_valid_leaf(plf), slab,
+                    n_entries=n_leaves,
+                )
             return _Carry(best_d, best_i, pairs, overflow)
 
-        init = _Carry(
-            best_d=jnp.full((q_total, k), jnp.inf, jnp.float32),
-            best_i=jnp.full((q_total, k), INVALID_ID, jnp.int32),
-            pairs=jnp.zeros((), jnp.float32),
-            overflow=jnp.zeros((), jnp.int32),
-        )
-        # the carry varies across shards (each shard scans its own rows)
-        init = jax.tree.map(lambda x: pcast_varying(x, axes), init)
+        with jax.named_scope(phases.CARRY):
+            init = _Carry(
+                best_d=jnp.full((q_total, k), jnp.inf, jnp.float32),
+                best_i=jnp.full((q_total, k), INVALID_ID, jnp.int32),
+                pairs=jnp.zeros((), jnp.float32),
+                overflow=jnp.zeros((), jnp.int32),
+            )
+            # the carry varies across shards (each shard scans its own rows)
+            init = jax.tree.map(lambda x: pcast_varying(x, axes), init)
         out = jax.lax.fori_loop(0, n_waves, wave, init)
-        pairs = jax.lax.psum(out.pairs, axes)
-        overflow = jax.lax.psum(out.overflow, axes)
+        with jax.named_scope(phases.COUNT):
+            pairs = jax.lax.psum(out.pairs, axes)
+            overflow = jax.lax.psum(out.overflow, axes)
         return out.best_d[None], out.best_i[None], pairs, overflow
 
     def pipeline(index: DistributedIndex, lookup: LookupTable) -> SearchResult:
@@ -263,6 +271,15 @@ def _point_major_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows, q_total,
     return pipeline
 
 
+def _routed_query_cap(plan: SearchPlan, q_total: int, n_shards: int) -> int:
+    """Query rows each shard holds after the query-routed shuffle."""
+    return round_up(
+        max(plan.q_tile,
+            int(q_total / n_shards * plan.query_capacity_factor)),
+        plan.q_tile,
+    )
+
+
 def _query_routed_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows, q_total,
                      axes):
     q_tile, p_cap, k = plan.q_tile, plan.p_cap, plan.k
@@ -270,76 +287,81 @@ def _query_routed_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows, q_total,
     if n_leaves % n_shards:
         raise ValueError(f"{n_leaves=} must divide over {n_shards} shards")
     lps = n_leaves // n_shards
-    q_cap_shard = round_up(
-        max(q_tile, int(q_total / n_shards * plan.query_capacity_factor)),
-        q_tile,
-    )
+    q_cap_shard = _routed_query_cap(plan, q_total, n_shards)
     n_qwaves = q_cap_shard // q_tile
 
     def shard_fn(vecs, leaves, ids, offsets, lk_vecs, lk_leaves, lk_qids):
         vecs, leaves, ids, offsets = vecs[0], leaves[0], ids[0], offsets[0]
-        leaf_base = _shard_id(mesh, axes) * lps
-        # ---- shuffle: route query rows to their leaf's owner shard --------
-        routed = route_lib.route_by_leaf(
-            lk_vecs,
-            lk_qids,
-            lk_leaves,
-            axis_name=axes,
-            n_shards=n_shards,
-            leaves_per_shard=lps,
-            capacity=q_cap_shard // n_shards,
-            wire_dtype=plan.wire_dtype,
-        )
-        qv_all, qids_all, qlf_all, _, _ = route_lib.cluster_sort(
-            routed, leaf_base=leaf_base, leaves_per_shard=lps
-        )
-        # pad/trim the local query set to the static budget
-        pad = q_cap_shard - qv_all.shape[0]
-        if pad > 0:
-            qv_all = jnp.concatenate(
-                [qv_all, jnp.zeros((pad, qv_all.shape[1]), qv_all.dtype)]
+        with jax.named_scope(phases.LOOKUP):
+            leaf_base = _shard_id(mesh, axes) * lps
+            # ---- shuffle: route query rows to their leaf's owner shard ----
+            routed = route_lib.route_by_leaf(
+                lk_vecs,
+                lk_qids,
+                lk_leaves,
+                axis_name=axes,
+                n_shards=n_shards,
+                leaves_per_shard=lps,
+                capacity=q_cap_shard // n_shards,
+                wire_dtype=plan.wire_dtype,
             )
-            qids_all = jnp.concatenate(
-                [qids_all, jnp.full((pad,), INVALID_ID, jnp.int32)]
+            qv_all, qids_all, qlf_all, _, _ = route_lib.cluster_sort(
+                routed, leaf_base=leaf_base, leaves_per_shard=lps
             )
-            qlf_all = jnp.concatenate(
-                [qlf_all, jnp.full((pad,), LEAF_SENTINEL, jnp.int32)]
-            )
-        else:
-            qv_all = qv_all[:q_cap_shard]
-            qids_all = qids_all[:q_cap_shard]
-            qlf_all = qlf_all[:q_cap_shard]
+            # pad/trim the local query set to the static budget
+            pad = q_cap_shard - qv_all.shape[0]
+            if pad > 0:
+                qv_all = jnp.concatenate(
+                    [qv_all, jnp.zeros((pad, qv_all.shape[1]), qv_all.dtype)]
+                )
+                qids_all = jnp.concatenate(
+                    [qids_all, jnp.full((pad,), INVALID_ID, jnp.int32)]
+                )
+                qlf_all = jnp.concatenate(
+                    [qlf_all, jnp.full((pad,), LEAF_SENTINEL, jnp.int32)]
+                )
+            else:
+                qv_all = qv_all[:q_cap_shard]
+                qids_all = qids_all[:q_cap_shard]
+                qlf_all = qlf_all[:q_cap_shard]
 
         def wave(w):
-            qs = w * q_tile
-            qv = jax.lax.dynamic_slice(qv_all, (qs, 0), (q_tile, qv_all.shape[1]))
-            qlf = jax.lax.dynamic_slice(qlf_all, (qs,), (q_tile,))
-            # contiguous local point slab covering this tile's leaf span
-            slab = tilescan.leaf_slab(
-                offsets, qlf[0] - leaf_base, n_entries=lps,
-                total_rows=shard_rows, cap=p_cap,
-            )
-            pv = jax.lax.dynamic_slice(
-                vecs, (slab.start, 0), (p_cap, vecs.shape[1])
-            )
-            plf = jax.lax.dynamic_slice(leaves, (slab.start,), (p_cap,))
-            pid = jax.lax.dynamic_slice(ids, (slab.start,), (p_cap,))
+            with jax.named_scope(phases.SLICE):
+                qs = w * q_tile
+                qv = jax.lax.dynamic_slice(
+                    qv_all, (qs, 0), (q_tile, qv_all.shape[1])
+                )
+                qlf = jax.lax.dynamic_slice(qlf_all, (qs,), (q_tile,))
+                # contiguous local point slab covering this tile's leaf span
+                slab = tilescan.leaf_slab(
+                    offsets, qlf[0] - leaf_base, n_entries=lps,
+                    total_rows=shard_rows, cap=p_cap,
+                )
+                pv = jax.lax.dynamic_slice(
+                    vecs, (slab.start, 0), (p_cap, vecs.shape[1])
+                )
+                plf = jax.lax.dynamic_slice(leaves, (slab.start,), (p_cap,))
+                pid = jax.lax.dynamic_slice(ids, (slab.start,), (p_cap,))
             cand_d, cand_i = tilescan.scan_tile(
                 pv, plf, pid, qv, qlf, k=k, impl=plan.impl
             )
-            cand_d = cand_d + sq_norms(qv)[:, None]  # true squared distance
-            ov = tilescan.slab_overflow(
-                offsets, tilescan.last_valid_leaf(qlf, base=leaf_base), slab,
-                n_entries=lps,
-            )
-            pairs = tilescan.count_pairs(plf, qlf)
+            with jax.named_scope(phases.DISTANCE):
+                # true squared distance
+                cand_d = cand_d + sq_norms(qv)[:, None]
+            with jax.named_scope(phases.COUNT):
+                ov = tilescan.slab_overflow(
+                    offsets, tilescan.last_valid_leaf(qlf, base=leaf_base),
+                    slab, n_entries=lps,
+                )
+                pairs = tilescan.count_pairs(plf, qlf)
             return cand_d, cand_i, ov, pairs
 
         cand_d, cand_i, ov, pairs = jax.lax.map(wave, jnp.arange(n_qwaves))
-        overflow = jax.lax.psum(jnp.sum(ov), axes) + jax.lax.psum(
-            routed.overflow, axes
-        )
-        pairs = jax.lax.psum(jnp.sum(pairs), axes)
+        with jax.named_scope(phases.COUNT):
+            overflow = jax.lax.psum(jnp.sum(ov), axes) + jax.lax.psum(
+                routed.overflow, axes
+            )
+            pairs = jax.lax.psum(jnp.sum(pairs), axes)
         return (
             cand_d.reshape(1, q_cap_shard, k),
             cand_i.reshape(1, q_cap_shard, k),
@@ -367,26 +389,28 @@ def _query_routed_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows, q_total,
         # one global scatter back to flat slot order (each lookup row was
         # answered by exactly one shard — no cross-shard merge needed),
         # then merge each query's probe rows
-        flat_d = cand_d.reshape(-1, k)
-        flat_i = cand_i.reshape(-1, k)
-        flat_q = qids.reshape(-1)
-        safe_q = jnp.where(flat_q >= 0, flat_q, q_total)
-        out_d = jnp.full((q_total, k), jnp.inf, jnp.float32).at[safe_q].set(
-            flat_d, mode="drop"
-        )
-        out_i = jnp.full((q_total, k), INVALID_ID, jnp.int32).at[safe_q].set(
-            flat_i, mode="drop"
-        )
-        out_d, out_i = tilescan.merge_probe_groups(out_d, out_i, plan.probes)
-        row_sh = NamedSharding(mesh, P(axes, None))
-        out_d = jax.lax.with_sharding_constraint(out_d, row_sh)
-        out_i = jax.lax.with_sharding_constraint(out_i, row_sh)
+        with jax.named_scope(phases.MERGE):
+            flat_d = cand_d.reshape(-1, k)
+            flat_i = cand_i.reshape(-1, k)
+            flat_q = qids.reshape(-1)
+            safe_q = jnp.where(flat_q >= 0, flat_q, q_total)
+            out_d = jnp.full((q_total, k), jnp.inf, jnp.float32).at[
+                safe_q].set(flat_d, mode="drop")
+            out_i = jnp.full((q_total, k), INVALID_ID, jnp.int32).at[
+                safe_q].set(flat_i, mode="drop")
+            out_d, out_i = tilescan.merge_probe_groups(
+                out_d, out_i, plan.probes
+            )
+            row_sh = NamedSharding(mesh, P(axes, None))
+            out_d = jax.lax.with_sharding_constraint(out_d, row_sh)
+            out_i = jax.lax.with_sharding_constraint(out_i, row_sh)
         return SearchResult(ids=out_i, dists=out_d, pairs=pairs,
                             q_cap_overflow=overflow)
 
     return pipeline
 
 
+@jax.named_scope(phases.DISTANCE)
 def _build_adc_lut(lookup_vecs, codebooks, *, q_total: int, m: int,
                    n_centers: int):
     """Per-lookup-row ADC tables, flattened to (Q, m * n_centers):
@@ -433,58 +457,63 @@ def _scan_codes_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows, q_total,
         codes, leaves, ids = codes[0], leaves[0], ids[0]
 
         def wave(i, c: _Carry) -> _Carry:
-            start = i * block_rows
-            pc = jax.lax.dynamic_slice(codes, (start, 0), (block_rows, m))
-            plf = jax.lax.dynamic_slice(leaves, (start,), (block_rows,))
-            pid = jax.lax.dynamic_slice(ids, (start,), (block_rows,))
-            slab = tilescan.leaf_slab(
-                lk_offsets, plf[0], n_entries=n_leaves, total_rows=q_total,
-                cap=q_cap,
+            with jax.named_scope(phases.SLICE):
+                start = i * block_rows
+                pc = jax.lax.dynamic_slice(codes, (start, 0), (block_rows, m))
+                plf = jax.lax.dynamic_slice(leaves, (start,), (block_rows,))
+                pid = jax.lax.dynamic_slice(ids, (start,), (block_rows,))
+                slab = tilescan.leaf_slab(
+                    lk_offsets, plf[0], n_entries=n_leaves,
+                    total_rows=q_total, cap=q_cap,
+                )
+                lut = jax.lax.dynamic_slice(
+                    lk_lut, (slab.start, 0), (q_cap, m * n_centers)
+                ).reshape(q_cap, m, n_centers)
+                qlf = jax.lax.dynamic_slice(lk_leaves, (slab.start,), (q_cap,))
+            with jax.named_scope(phases.DISTANCE):
+                # tombstoned rows keep their leaf for slab location but
+                # must never match: codes can't carry the 1e15 vec mask the
+                # dense scan uses, so mask the *match* leaves by id validity
+                plf_m = jnp.where(pid >= 0, plf, PAD_TILE_POINT_LEAF)
+                cand_d, cand_sel = adc_ops.adc_topk(
+                    pc, plf_m, lut, qlf, k=r, impl=plan.impl
+                )
+            with jax.named_scope(phases.SELECT):
+                cand_i = jnp.where(
+                    cand_sel >= 0, pid[jnp.clip(cand_sel, 0)], INVALID_ID
+                )
+                cand_d = jnp.where(cand_i >= 0, cand_d, jnp.inf)
+            best_d, best_i = tilescan.fold_rows(
+                c.best_d, c.best_i, cand_d, cand_i, slab.start
             )
-            lut = jax.lax.dynamic_slice(
-                lk_lut, (slab.start, 0), (q_cap, m * n_centers)
-            ).reshape(q_cap, m, n_centers)
-            qlf = jax.lax.dynamic_slice(lk_leaves, (slab.start,), (q_cap,))
-            # tombstoned rows keep their leaf for slab location but must
-            # never match: codes can't carry the 1e15 vec mask the dense
-            # scan uses, so mask the *match* leaves by id validity
-            plf_m = jnp.where(pid >= 0, plf, PAD_TILE_POINT_LEAF)
-            cand_d, cand_sel = adc_ops.adc_topk(
-                pc, plf_m, lut, qlf, k=r, impl=plan.impl
-            )
-            cand_i = jnp.where(
-                cand_sel >= 0, pid[jnp.clip(cand_sel, 0)], INVALID_ID
-            )
-            cand_d = jnp.where(cand_i >= 0, cand_d, jnp.inf)
-            cur_d = jax.lax.dynamic_slice(c.best_d, (slab.start, 0), (q_cap, r))
-            cur_i = jax.lax.dynamic_slice(c.best_i, (slab.start, 0), (q_cap, r))
-            new_d, new_i = tilescan.fold_topk(cur_d, cur_i, cand_d, cand_i)
-            best_d = jax.lax.dynamic_update_slice(c.best_d, new_d, (slab.start, 0))
-            best_i = jax.lax.dynamic_update_slice(c.best_i, new_i, (slab.start, 0))
-            pairs = c.pairs + tilescan.count_pairs(plf_m, qlf)
-            overflow = c.overflow + tilescan.slab_overflow(
-                lk_offsets, tilescan.last_valid_leaf(plf), slab,
-                n_entries=n_leaves,
-            )
+            with jax.named_scope(phases.COUNT):
+                pairs = c.pairs + tilescan.count_pairs(plf_m, qlf)
+                overflow = c.overflow + tilescan.slab_overflow(
+                    lk_offsets, tilescan.last_valid_leaf(plf), slab,
+                    n_entries=n_leaves,
+                )
             return _Carry(best_d, best_i, pairs, overflow)
 
-        init = _Carry(
-            best_d=jnp.full((q_total, r), jnp.inf, jnp.float32),
-            best_i=jnp.full((q_total, r), INVALID_ID, jnp.int32),
-            pairs=jnp.zeros((), jnp.float32),
-            overflow=jnp.zeros((), jnp.int32),
-        )
-        init = jax.tree.map(lambda x: pcast_varying(x, axes), init)
+        with jax.named_scope(phases.CARRY):
+            init = _Carry(
+                best_d=jnp.full((q_total, r), jnp.inf, jnp.float32),
+                best_i=jnp.full((q_total, r), INVALID_ID, jnp.int32),
+                pairs=jnp.zeros((), jnp.float32),
+                overflow=jnp.zeros((), jnp.int32),
+            )
+            init = jax.tree.map(lambda x: pcast_varying(x, axes), init)
         out = jax.lax.fori_loop(0, n_waves, wave, init)
-        pairs = jax.lax.psum(out.pairs, axes)
-        overflow = jax.lax.psum(out.overflow, axes)
+        with jax.named_scope(phases.COUNT):
+            pairs = jax.lax.psum(out.pairs, axes)
+            overflow = jax.lax.psum(out.overflow, axes)
         return out.best_d[None], out.best_i[None], pairs, overflow
 
     def pipeline(index: DistributedIndex, lookup: LookupTable,
                  codes: jax.Array, codebooks: jax.Array) -> SearchResult:
         lut = _build_adc_lut(lookup.vecs, codebooks, q_total=q_total, m=m,
                              n_centers=n_centers)
-        codes3 = codes.astype(jnp.int32).reshape(n_shards, shard_rows, m)
+        with jax.named_scope(phases.SLICE):
+            codes3 = codes.astype(jnp.int32).reshape(n_shards, shard_rows, m)
         leaves = index.leaves.reshape(n_shards, shard_rows)
         ids = index.ids.reshape(n_shards, shard_rows)
         row_spec = P(axes, None)
@@ -535,20 +564,23 @@ def _point_major_fused_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows,
         from repro.kernels.fusedscan import ops as fused_ops
 
         vecs, leaves, ids = vecs[0], leaves[0], ids[0]
-        best_d, best_i = fused_ops.fused_topk(
-            vecs, leaves, ids, lk_vecs, lk_leaves, k=k, impl="pallas",
-        )
-        pairs = jax.lax.psum(
-            _leaf_pair_count(leaves, lk_leaves, n_leaves), axes
-        )
-        # whole-shard scan: every leaf-matching query row is visible to
-        # every point tile — the q_cap slab budget cannot be exceeded
-        overflow = jax.lax.psum(jnp.zeros((), jnp.int32), axes)
+        with jax.named_scope(phases.DISTANCE):
+            best_d, best_i = fused_ops.fused_topk(
+                vecs, leaves, ids, lk_vecs, lk_leaves, k=k, impl="pallas",
+            )
+        with jax.named_scope(phases.COUNT):
+            pairs = jax.lax.psum(
+                _leaf_pair_count(leaves, lk_leaves, n_leaves), axes
+            )
+            # whole-shard scan: every leaf-matching query row is visible to
+            # every point tile — the q_cap slab budget cannot be exceeded
+            overflow = jax.lax.psum(jnp.zeros((), jnp.int32), axes)
         return best_d[None], best_i[None], pairs, overflow
 
     def piped_shard_fn(vecs, leaves, ids, lk_vecs, lk_leaves, lk_offsets):
         vecs, leaves, ids = vecs[0], leaves[0], ids[0]
 
+        @jax.named_scope(phases.SLICE)
         def fetch(i):
             first = jax.lax.dynamic_slice(leaves, (i * block_rows,), (1,))[0]
             slab = tilescan.leaf_slab(
@@ -562,42 +594,46 @@ def _point_major_fused_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows,
             return qv, qlf, slab.start
 
         def wave(i, c: _PipedCarry) -> _PipedCarry:
-            start = i * block_rows
-            pv = jax.lax.dynamic_slice(vecs, (start, 0), (block_rows, vecs.shape[1]))
-            plf = jax.lax.dynamic_slice(leaves, (start,), (block_rows,))
-            pid = jax.lax.dynamic_slice(ids, (start,), (block_rows,))
+            with jax.named_scope(phases.SLICE):
+                start = i * block_rows
+                pv = jax.lax.dynamic_slice(
+                    vecs, (start, 0), (block_rows, vecs.shape[1])
+                )
+                plf = jax.lax.dynamic_slice(leaves, (start,), (block_rows,))
+                pid = jax.lax.dynamic_slice(ids, (start,), (block_rows,))
             # scan the slab prefetched by the previous iteration
             cand_d, cand_i = tilescan.scan_tile(
                 pv, plf, pid, c.qv, c.qlf, k=k, impl="xla"
             )
-            cur_d = jax.lax.dynamic_slice(c.best_d, (c.slab_start, 0), (q_cap, k))
-            cur_i = jax.lax.dynamic_slice(c.best_i, (c.slab_start, 0), (q_cap, k))
-            new_d, new_i = tilescan.fold_topk(cur_d, cur_i, cand_d, cand_i)
-            best_d = jax.lax.dynamic_update_slice(c.best_d, new_d, (c.slab_start, 0))
-            best_i = jax.lax.dynamic_update_slice(c.best_i, new_i, (c.slab_start, 0))
-            pairs = c.pairs + tilescan.count_pairs(plf, c.qlf)
-            overflow = c.overflow + tilescan.slab_overflow(
-                lk_offsets, tilescan.last_valid_leaf(plf),
-                tilescan.Slab(start=c.slab_start, cap=q_cap),
-                n_entries=n_leaves,
+            best_d, best_i = tilescan.fold_rows(
+                c.best_d, c.best_i, cand_d, cand_i, c.slab_start
             )
+            with jax.named_scope(phases.COUNT):
+                pairs = c.pairs + tilescan.count_pairs(plf, c.qlf)
+                overflow = c.overflow + tilescan.slab_overflow(
+                    lk_offsets, tilescan.last_valid_leaf(plf),
+                    tilescan.Slab(start=c.slab_start, cap=q_cap),
+                    n_entries=n_leaves,
+                )
             # prefetch wave i+1's slab (clamped on the last wave)
             qv, qlf, slab_start = fetch(jnp.minimum(i + 1, n_waves - 1))
             return _PipedCarry(best_d, best_i, pairs, overflow, qv, qlf,
                                slab_start)
 
-        qv0, qlf0, start0 = fetch(0)
-        init = _PipedCarry(
-            best_d=jnp.full((q_total, k), jnp.inf, jnp.float32),
-            best_i=jnp.full((q_total, k), INVALID_ID, jnp.int32),
-            pairs=jnp.zeros((), jnp.float32),
-            overflow=jnp.zeros((), jnp.int32),
-            qv=qv0, qlf=qlf0, slab_start=start0,
-        )
-        init = jax.tree.map(lambda x: pcast_varying(x, axes), init)
+        with jax.named_scope(phases.CARRY):
+            qv0, qlf0, start0 = fetch(0)
+            init = _PipedCarry(
+                best_d=jnp.full((q_total, k), jnp.inf, jnp.float32),
+                best_i=jnp.full((q_total, k), INVALID_ID, jnp.int32),
+                pairs=jnp.zeros((), jnp.float32),
+                overflow=jnp.zeros((), jnp.int32),
+                qv=qv0, qlf=qlf0, slab_start=start0,
+            )
+            init = jax.tree.map(lambda x: pcast_varying(x, axes), init)
         out = jax.lax.fori_loop(0, n_waves, wave, init)
-        pairs = jax.lax.psum(out.pairs, axes)
-        overflow = jax.lax.psum(out.overflow, axes)
+        with jax.named_scope(phases.COUNT):
+            pairs = jax.lax.psum(out.pairs, axes)
+            overflow = jax.lax.psum(out.overflow, axes)
         return out.best_d[None], out.best_i[None], pairs, overflow
 
     shard_fn = kernel_shard_fn if use_kernel else piped_shard_fn
@@ -650,21 +686,24 @@ def _scan_codes_fused_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows,
         from repro.kernels.fusedscan import ops as fused_ops
 
         codes, leaves, ids = codes[0], leaves[0], ids[0]
-        # tombstoned rows must never match (see _scan_codes_fn)
-        plf_m = jnp.where(ids >= 0, leaves, PAD_TILE_POINT_LEAF)
-        best_d, best_i = fused_ops.fused_adc_topk(
-            codes, plf_m, ids, lk_lut.reshape(q_total, m, n_centers),
-            lk_leaves, k=r, impl="pallas",
-        )
-        pairs = jax.lax.psum(
-            _leaf_pair_count(plf_m, lk_leaves, n_leaves), axes
-        )
-        overflow = jax.lax.psum(jnp.zeros((), jnp.int32), axes)
+        with jax.named_scope(phases.DISTANCE):
+            # tombstoned rows must never match (see _scan_codes_fn)
+            plf_m = jnp.where(ids >= 0, leaves, PAD_TILE_POINT_LEAF)
+            best_d, best_i = fused_ops.fused_adc_topk(
+                codes, plf_m, ids, lk_lut.reshape(q_total, m, n_centers),
+                lk_leaves, k=r, impl="pallas",
+            )
+        with jax.named_scope(phases.COUNT):
+            pairs = jax.lax.psum(
+                _leaf_pair_count(plf_m, lk_leaves, n_leaves), axes
+            )
+            overflow = jax.lax.psum(jnp.zeros((), jnp.int32), axes)
         return best_d[None], best_i[None], pairs, overflow
 
     def piped_shard_fn(codes, leaves, ids, lk_lut, lk_leaves, lk_offsets):
         codes, leaves, ids = codes[0], leaves[0], ids[0]
 
+        @jax.named_scope(phases.SLICE)
         def fetch(i):
             first = jax.lax.dynamic_slice(leaves, (i * block_rows,), (1,))[0]
             slab = tilescan.leaf_slab(
@@ -680,46 +719,50 @@ def _scan_codes_fused_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows,
         def wave(i, c: _PipedCarry) -> _PipedCarry:
             from repro.kernels.adcscan import ops as adc_ops
 
-            start = i * block_rows
-            pc = jax.lax.dynamic_slice(codes, (start, 0), (block_rows, m))
-            plf = jax.lax.dynamic_slice(leaves, (start,), (block_rows,))
-            pid = jax.lax.dynamic_slice(ids, (start,), (block_rows,))
-            plf_m = jnp.where(pid >= 0, plf, PAD_TILE_POINT_LEAF)
-            cand_d, cand_sel = adc_ops.adc_topk(
-                pc, plf_m, c.qv.reshape(q_cap, m, n_centers), c.qlf, k=r,
-                impl="xla",
+            with jax.named_scope(phases.SLICE):
+                start = i * block_rows
+                pc = jax.lax.dynamic_slice(codes, (start, 0), (block_rows, m))
+                plf = jax.lax.dynamic_slice(leaves, (start,), (block_rows,))
+                pid = jax.lax.dynamic_slice(ids, (start,), (block_rows,))
+            with jax.named_scope(phases.DISTANCE):
+                plf_m = jnp.where(pid >= 0, plf, PAD_TILE_POINT_LEAF)
+                cand_d, cand_sel = adc_ops.adc_topk(
+                    pc, plf_m, c.qv.reshape(q_cap, m, n_centers), c.qlf, k=r,
+                    impl="xla",
+                )
+            with jax.named_scope(phases.SELECT):
+                cand_i = jnp.where(
+                    cand_sel >= 0, pid[jnp.clip(cand_sel, 0)], INVALID_ID
+                )
+                cand_d = jnp.where(cand_i >= 0, cand_d, jnp.inf)
+            best_d, best_i = tilescan.fold_rows(
+                c.best_d, c.best_i, cand_d, cand_i, c.slab_start
             )
-            cand_i = jnp.where(
-                cand_sel >= 0, pid[jnp.clip(cand_sel, 0)], INVALID_ID
-            )
-            cand_d = jnp.where(cand_i >= 0, cand_d, jnp.inf)
-            cur_d = jax.lax.dynamic_slice(c.best_d, (c.slab_start, 0), (q_cap, r))
-            cur_i = jax.lax.dynamic_slice(c.best_i, (c.slab_start, 0), (q_cap, r))
-            new_d, new_i = tilescan.fold_topk(cur_d, cur_i, cand_d, cand_i)
-            best_d = jax.lax.dynamic_update_slice(c.best_d, new_d, (c.slab_start, 0))
-            best_i = jax.lax.dynamic_update_slice(c.best_i, new_i, (c.slab_start, 0))
-            pairs = c.pairs + tilescan.count_pairs(plf_m, c.qlf)
-            overflow = c.overflow + tilescan.slab_overflow(
-                lk_offsets, tilescan.last_valid_leaf(plf),
-                tilescan.Slab(start=c.slab_start, cap=q_cap),
-                n_entries=n_leaves,
-            )
+            with jax.named_scope(phases.COUNT):
+                pairs = c.pairs + tilescan.count_pairs(plf_m, c.qlf)
+                overflow = c.overflow + tilescan.slab_overflow(
+                    lk_offsets, tilescan.last_valid_leaf(plf),
+                    tilescan.Slab(start=c.slab_start, cap=q_cap),
+                    n_entries=n_leaves,
+                )
             lut, qlf, slab_start = fetch(jnp.minimum(i + 1, n_waves - 1))
             return _PipedCarry(best_d, best_i, pairs, overflow, lut, qlf,
                                slab_start)
 
-        lut0, qlf0, start0 = fetch(0)
-        init = _PipedCarry(
-            best_d=jnp.full((q_total, r), jnp.inf, jnp.float32),
-            best_i=jnp.full((q_total, r), INVALID_ID, jnp.int32),
-            pairs=jnp.zeros((), jnp.float32),
-            overflow=jnp.zeros((), jnp.int32),
-            qv=lut0, qlf=qlf0, slab_start=start0,
-        )
-        init = jax.tree.map(lambda x: pcast_varying(x, axes), init)
+        with jax.named_scope(phases.CARRY):
+            lut0, qlf0, start0 = fetch(0)
+            init = _PipedCarry(
+                best_d=jnp.full((q_total, r), jnp.inf, jnp.float32),
+                best_i=jnp.full((q_total, r), INVALID_ID, jnp.int32),
+                pairs=jnp.zeros((), jnp.float32),
+                overflow=jnp.zeros((), jnp.int32),
+                qv=lut0, qlf=qlf0, slab_start=start0,
+            )
+            init = jax.tree.map(lambda x: pcast_varying(x, axes), init)
         out = jax.lax.fori_loop(0, n_waves, wave, init)
-        pairs = jax.lax.psum(out.pairs, axes)
-        overflow = jax.lax.psum(out.overflow, axes)
+        with jax.named_scope(phases.COUNT):
+            pairs = jax.lax.psum(out.pairs, axes)
+            overflow = jax.lax.psum(out.overflow, axes)
         return out.best_d[None], out.best_i[None], pairs, overflow
 
     shard_fn = kernel_shard_fn if use_kernel else piped_shard_fn
@@ -728,7 +771,8 @@ def _scan_codes_fused_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows,
                  codes: jax.Array, codebooks: jax.Array) -> SearchResult:
         lut = _build_adc_lut(lookup.vecs, codebooks, q_total=q_total, m=m,
                              n_centers=n_centers)
-        codes3 = codes.astype(jnp.int32).reshape(n_shards, shard_rows, m)
+        with jax.named_scope(phases.SLICE):
+            codes3 = codes.astype(jnp.int32).reshape(n_shards, shard_rows, m)
         leaves = index.leaves.reshape(n_shards, shard_rows)
         ids = index.ids.reshape(n_shards, shard_rows)
         row_spec = P(axes, None)
@@ -797,3 +841,26 @@ def make_executor(
         mesh, plan, n_leaves=n_leaves, shard_rows=shard_rows, q_total=q_total,
         axes=axes,
     )
+
+
+def pairs_computed(plan: SearchPlan, *, shard_rows: int, q_total: int,
+                   n_shards: int) -> int:
+    """Distance pairs one run of ``plan``'s executor evaluates, useful or
+    not: every (point row, query row) cell of the tiles it scans, summed
+    over shards. ``SearchResult.pairs`` counts the same-leaf cells among
+    them, so the two give the scan's pair yield.
+
+    Point-major and codes sweeps visit every ``block_rows`` wave against a
+    ``q_cap`` query slab (``shard_rows * q_cap``); query-routed visits every
+    ``q_tile`` of its routed query rows against a ``p_cap`` point slab; the
+    whole-shard fused kernel meets every point row with every lookup row
+    (before tile padding).
+    """
+    plan = plan.resolved()
+    if plan.layout == "query_routed":
+        per_shard = _routed_query_cap(plan, q_total, n_shards) * plan.p_cap
+    elif plan.impl == "fused" and _fused_wants_kernel():
+        per_shard = shard_rows * q_total
+    else:
+        per_shard = shard_rows * plan.q_cap
+    return int(per_shard) * int(n_shards)

@@ -16,6 +16,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import phases
 from repro.core.sentinels import INVALID_ID, LEAF_SENTINEL
 from repro.kernels.l2topk import ops as l2topk_ops
 
@@ -27,6 +28,7 @@ class Slab(NamedTuple):
     cap: int  # static slab row budget
 
 
+@jax.named_scope(phases.SLICE)
 def leaf_slab(
     offsets: jax.Array, first_leaf: jax.Array, *, n_entries: int,
     total_rows: int, cap: int
@@ -42,6 +44,7 @@ def leaf_slab(
     return Slab(start=start, cap=cap)
 
 
+@jax.named_scope(phases.COUNT)
 def slab_overflow(
     offsets: jax.Array, last_leaf: jax.Array, slab: Slab, *, n_entries: int
 ) -> jax.Array:
@@ -59,6 +62,7 @@ def slab_overflow(
     return jnp.maximum(0, need_end - slab.start - slab.cap).astype(jnp.int32)
 
 
+@jax.named_scope(phases.COUNT)
 def last_valid_leaf(leaves: jax.Array, *, base=0) -> jax.Array:
     """Highest real leaf id in a tile, shifted by ``base``; -1 if none."""
     valid = leaves != LEAF_SENTINEL
@@ -82,12 +86,17 @@ def scan_tile(
     than ``k`` same-leaf points exist. ``cand_i`` holds *global* descriptor
     ids (mapped through ``pid``), not tile-row indices.
     """
-    cand_d, cand_sel = l2topk_ops.l2_topk(pv, plf, qv, qlf, k=k, impl=impl)
-    cand_i = jnp.where(cand_sel >= 0, pid[jnp.clip(cand_sel, 0)], INVALID_ID)
-    cand_d = jnp.where(cand_i >= 0, cand_d, jnp.inf)
+    with jax.named_scope(phases.DISTANCE):  # the ref names its own select
+        cand_d, cand_sel = l2topk_ops.l2_topk(pv, plf, qv, qlf, k=k,
+                                              impl=impl)
+    with jax.named_scope(phases.SELECT):
+        cand_i = jnp.where(cand_sel >= 0, pid[jnp.clip(cand_sel, 0)],
+                           INVALID_ID)
+        cand_d = jnp.where(cand_i >= 0, cand_d, jnp.inf)
     return cand_d, cand_i
 
 
+@jax.named_scope(phases.COUNT)
 def count_pairs(plf: jax.Array, qlf: jax.Array) -> jax.Array:
     """Exact number of same-leaf (point, query) distance pairs in a tile.
 
@@ -101,6 +110,7 @@ def count_pairs(plf: jax.Array, qlf: jax.Array) -> jax.Array:
     return jnp.sum(match, dtype=jnp.float32)
 
 
+@jax.named_scope(phases.SELECT)
 def fold_topk(
     cur_d: jax.Array, cur_i: jax.Array, cand_d: jax.Array, cand_i: jax.Array
 ) -> tuple[jax.Array, jax.Array]:
@@ -112,6 +122,23 @@ def fold_topk(
     return -neg, jnp.take_along_axis(all_i, sel, axis=-1)
 
 
+def fold_rows(
+    best_d: jax.Array, best_i: jax.Array, cand_d: jax.Array,
+    cand_i: jax.Array, start: jax.Array
+) -> tuple[jax.Array, jax.Array]:
+    """Fold a ``(rows, w)`` candidate table into rows ``[start, start +
+    rows)`` of the running best table: read them, merge, write back."""
+    rows, w = cand_d.shape
+    with jax.named_scope(phases.SLICE):
+        cur_d = jax.lax.dynamic_slice(best_d, (start, 0), (rows, w))
+        cur_i = jax.lax.dynamic_slice(best_i, (start, 0), (rows, w))
+    new_d, new_i = fold_topk(cur_d, cur_i, cand_d, cand_i)
+    with jax.named_scope(phases.CARRY):
+        return (jax.lax.dynamic_update_slice(best_d, new_d, (start, 0)),
+                jax.lax.dynamic_update_slice(best_i, new_i, (start, 0)))
+
+
+@jax.named_scope(phases.SELECT)
 def merge_probe_groups(
     d: jax.Array, i: jax.Array, probes: int
 ) -> tuple[jax.Array, jax.Array]:

@@ -15,6 +15,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro.core import phases
 from repro.core.distance import PRECISION, sq_norms
 from repro.core.sentinels import PAD_QUERY_LEAF
 from repro.core.tree import VocabTree, tree_assign
@@ -111,6 +112,7 @@ def probe_leaves(tree: VocabTree, queries: jax.Array, probes: int) -> jax.Array:
     return jnp.take_along_axis(nodes, order, axis=1).astype(jnp.int32)
 
 
+@jax.named_scope(phases.LOOKUP)
 def build_lookup(
     tree: VocabTree, queries: jax.Array, *, probes: int = 1
 ) -> LookupTable:
@@ -207,6 +209,7 @@ def lookup_from_leaves(
     )
 
 
+@jax.named_scope(phases.LOOKUP)
 def build_lookup_bucketed(
     tree: VocabTree,
     queries: jax.Array,

@@ -24,16 +24,20 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core import phases
+
 
 def adc_topk_ref(codes, point_leaves, lut, query_leaves, k: int):
-    c = codes.astype(jnp.int32)
-    m = c.shape[1]
-    d2 = jnp.zeros((lut.shape[0], c.shape[0]), jnp.float32)
-    for j in range(m):  # m is static and small (bytes per row)
-        d2 = d2 + jnp.take(lut[:, j, :], c[:, j], axis=1)  # (Q, P)
-    match = query_leaves[:, None] == point_leaves[None, :]
-    d2 = jnp.where(match, d2, jnp.inf)
-    neg, sel = jax.lax.top_k(-d2, k)  # (Q, k) over code rows
-    dists = -neg
-    idx = jnp.where(jnp.isfinite(dists), sel, -1).astype(jnp.int32)
+    with jax.named_scope(phases.DISTANCE):
+        c = codes.astype(jnp.int32)
+        m = c.shape[1]
+        d2 = jnp.zeros((lut.shape[0], c.shape[0]), jnp.float32)
+        for j in range(m):  # m is static and small (bytes per row)
+            d2 = d2 + jnp.take(lut[:, j, :], c[:, j], axis=1)  # (Q, P)
+        match = query_leaves[:, None] == point_leaves[None, :]
+        d2 = jnp.where(match, d2, jnp.inf)
+    with jax.named_scope(phases.SELECT):
+        neg, sel = jax.lax.top_k(-d2, k)  # (Q, k) over code rows
+        dists = -neg
+        idx = jnp.where(jnp.isfinite(dists), sel, -1).astype(jnp.int32)
     return dists, idx

@@ -21,6 +21,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.core import phases
 from repro.core.sentinels import (
     INVALID_ID,
     PAD_TILE_POINT_LEAF,
@@ -45,6 +46,7 @@ def _pad_leaves(leaves, n: int, pad_leaf: int):
     return out.at[: leaves.shape[0]].set(leaves.astype(jnp.int32))
 
 
+@jax.named_scope(phases.SELECT)
 def _map_ids(out_d, sel, point_ids, Q: int):
     ids = jnp.where(
         sel >= 0, point_ids[jnp.clip(sel, 0)], jnp.int32(INVALID_ID)

@@ -9,7 +9,9 @@ wall time), so the tracer supports both domains on one timeline:
   * ``tracer.span(name, ...)`` — a context manager measuring wall time
     (re-based through the active :meth:`Tracer.timebase`, so engine work
     nested inside a virtual-time dispatch lands at the dispatch's virtual
-    timestamp);
+    timestamp). It also opens a ``jax.profiler.TraceAnnotation`` of the
+    same name for its lifetime, so under a running profiler every such
+    span lands on the profile's host plane, on the device ops' clock;
   * ``tracer.add_span(name, t0, t1, ...)`` — an explicit interval in
     caller-supplied (virtual) seconds, used by the micro-batcher for the
     request / queue-wait / compute bars;
@@ -25,7 +27,7 @@ Two hard requirements shape the design:
   * **never perturb results** — the tracer only *records*; nothing in it
     feeds back into planning, scheduling, or the engine, so ids and
     distances are bit-identical with tracing on or off (asserted by
-    tests/test_obs.py and the ``--obs-smoke`` gate).
+    tests/test_obs.py).
 
 Sampling is deterministic: :meth:`Tracer.sampled` hashes ``(seed,
 trace_id)``, so the same seed always traces the same request subset
@@ -41,6 +43,8 @@ import contextlib
 import dataclasses
 import time
 import zlib
+
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -254,7 +258,10 @@ class Tracer:
     def span(self, name: str, *, trace_id=None, parent=None, **attrs):
         """Measure the enclosed block as one span (wall time, re-based by
         the active :meth:`timebase`). Nested ``span()`` blocks parent
-        automatically; explicit ``parent`` overrides."""
+        automatically; explicit ``parent`` overrides. The block also runs
+        inside ``jax.profiler.TraceAnnotation(name)``, which a running
+        profiler records on its host plane (and which costs next to
+        nothing when none runs)."""
         if parent is None and self._stack:
             parent = self._stack[-1]
         pid = parent.span_id if isinstance(parent, (Span, _NullSpan)) \
@@ -267,7 +274,8 @@ class Tracer:
         if real:
             self._stack.append(span)
         try:
-            yield span
+            with TraceAnnotation(name):
+                yield span
         finally:
             if real:
                 self._stack.pop()

@@ -192,6 +192,10 @@ class ServingMetrics:
     queue_depth: list = dataclasses.field(default_factory=list)  # samples
     per_class: dict = dataclasses.field(default_factory=dict)
     max_samples: int | None = None  # bound per-collector memory (None=exact)
+    # same-leaf distance pairs the engine's scans needed (SearchResult.pairs)
+    # and the pairs their tiles evaluated: the scan's pair yield
+    pairs_useful: int = 0
+    pairs_computed: int = 0
 
     def __post_init__(self):
         if self.max_samples is not None:
@@ -313,6 +317,8 @@ class ServingMetrics:
             out[f"serving.class.rejected{lbl}"] = cm.rejected
             out[f"serving.class.attained{lbl}"] = cm.attained
             out[f"serving.class.latency.hist{lbl}"] = cm.latency.histogram()
+        out["serving.pairs_useful"] = self.pairs_useful
+        out["serving.pairs_computed"] = self.pairs_computed
         return out
 
     def to_dict(self) -> dict:
@@ -343,4 +349,6 @@ class ServingMetrics:
                     self.per_class.items()
                 )
             },
+            "pairs_useful": self.pairs_useful,
+            "pairs_computed": self.pairs_computed,
         }

@@ -32,6 +32,7 @@ difference between 5 ms and 5 s. The session closes that hole:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Sequence
 
@@ -50,14 +51,19 @@ from repro.core.engine import (
     scale_slab_budget,
     snap_to_bucket,
 )
-from repro.core.engine.executors import SearchResult, pad_lookup
+from repro.core import phases
+from repro.core.engine.executors import (
+    SearchResult,
+    pad_lookup,
+    pairs_computed,
+)
 from repro.core.index_build import DistributedIndex
 from repro.core.lookup import build_lookup_bucketed
 from repro.core.search import lookup_q_total
 from repro.core.engine.costmodel import plan_signature, signature_key
 from repro.core.tree import VocabTree
 from repro.distributed.meshutil import data_axis_size, local_mesh
-from repro.obs import get_tracer
+from repro.obs import get_registry, get_tracer
 from repro.serving.cache import HotLeafCache
 from repro.serving.metrics import ServingMetrics
 
@@ -82,6 +88,9 @@ class _BucketRuntime:
     # emits (the caller reranks exactly), and the fused fn's signature
     # grows to (segments, codes, codebooks, tree, queries, n_valid)
     rerank: int | None = None
+    # distance pairs one dispatch evaluates over every segment's tiles,
+    # useful or not (executors.pairs_computed)
+    pairs_computed: int = 0
 
 
 def make_bucket_runtime(
@@ -190,6 +199,7 @@ def make_bucket_runtime(
         for g in ordinals
     ])
 
+    @jax.named_scope(phases.MERGE)
     def merge(outs, leaves):
         if len(outs) == 1 and not emit_slots:
             return outs[0], leaves
@@ -252,7 +262,22 @@ def make_bucket_runtime(
             for bp, v in zip(base_plans, segments)
         ),
         rerank=r if use_codes else None,
+        pairs_computed=sum(
+            pairs_computed(p, shard_rows=v.rows // n_shards, q_total=qt,
+                           n_shards=n_shards)
+            for p, v, qt in zip(plans, segments, q_totals)
+        ),
     )
+
+
+def _retrace(f):
+    """A new function object calling ``f``, which ``jax.jit`` traces
+    anew (its caches key on the function object)."""
+    @functools.wraps(f)
+    def g(*args):
+        return f(*args)
+
+    return g
 
 
 def attach_cache(cache: HotLeafCache, views, n_leaves: int) -> None:
@@ -644,13 +669,57 @@ class SearchSession:
     def max_batch_rows(self) -> int:
         return self.buckets[-1]
 
-    def _dispatch(self, rt: _BucketRuntime, buf, n_valid):
-        """Invoke one rung's fused pipeline (codes rungs take the device
-        codes + codebook table as extra leading arguments)."""
+    def _args(self, rt: _BucketRuntime, buf, n_valid) -> tuple:
+        """One rung's call arguments (codes rungs take the device codes +
+        codebook table as extra leading arguments)."""
         if rt.rerank is not None:
-            return rt.fn(self._segments, self._codes_dev,
-                         self._codebooks_dev, self.tree, buf, n_valid)
-        return rt.fn(self._segments, self.tree, buf, n_valid)
+            return (self._segments, self._codes_dev, self._codebooks_dev,
+                    self.tree, buf, n_valid)
+        return (self._segments, self.tree, buf, n_valid)
+
+    def _dispatch(self, rt: _BucketRuntime, buf, n_valid):
+        """Invoke one rung's fused pipeline."""
+        return rt.fn(*self._args(rt, buf, n_valid))
+
+    def _programs(self):
+        """``(bucket, jitted fn, arguments)`` of every warmed program, on
+        an empty batch of the rung's shape."""
+        for b, rt in self._runtimes.items():
+            dummy = jnp.zeros((b, self.index.dim), jnp.float32)
+            yield b, rt.fn, self._args(rt, dummy, np.int32(0))
+
+    def compiled_hlo(self, buckets: Sequence[int] | None = None) -> list[str]:
+        """The optimized HLO text of each warmed program (of ``buckets``
+        only, when given), compiled for the backend that serves it.
+
+        Every instruction's ``op_name`` metadata carries the device phase
+        it belongs to (:mod:`repro.core.phases`), so a profiler trace's
+        ops can be read as time per phase (docs/observability.md). Each
+        program is traced and compiled afresh, with the arguments serving
+        uses and past JAX's in-memory and persistent compilation caches:
+        their keys leave metadata out, so a cached executable may carry
+        the metadata of an earlier source of the same program. The
+        compiler is deterministic, so the instruction names are those of
+        the executable that serves; that one is left untouched, and
+        nothing runs on the device. Switches the persistent cache off
+        process-wide while it compiles.
+        """
+        from jax.experimental.compilation_cache import compilation_cache
+
+        progs = [(fn, args) for b, fn, args in self._programs()
+                 if buckets is None or b in buckets]
+        enabled = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            return [
+                jax.jit(_retrace(fn.__wrapped__)).lower(*args).compile()
+                .as_text()
+                for fn, args in progs
+            ]
+        finally:
+            jax.config.update("jax_enable_compilation_cache", enabled)
+            compilation_cache.reset_cache()
 
     def _execute(
         self, queries: np.ndarray, *, n_images: int | None = None
@@ -659,7 +728,10 @@ class SearchSession:
 
         Returns ``(ids (n,k), dists (n,k), probe_leaves (n,probes),
         seconds)``; feeds metrics, the hot-leaf cache, and the plan's
-        ms/image observations.
+        ms/image observations. Traced, the call is one ``engine.execute``
+        span whose children place the host's part of it: ``session.pad``,
+        ``session.dispatch`` (H2D and enqueue), ``session.wait`` (the
+        device), ``session.fetch`` (D2H), then ``session.record``.
         """
         n, d = queries.shape
         if n > self.max_batch_rows:
@@ -668,40 +740,65 @@ class SearchSession:
                 f"{self.max_batch_rows}; split it across dispatches"
             )
         rt = self._runtimes[snap_to_bucket(n, self.buckets)]
-        buf = np.zeros((rt.bucket, d), np.float32)
-        buf[:n] = queries
-        t0 = time.perf_counter()
-        res, leaves = self._dispatch(rt, jnp.asarray(buf), np.int32(n))
-        jax.block_until_ready((res.ids, res.dists, leaves))
-        dt = time.perf_counter() - t0
-        ids = np.asarray(res.ids[:n])
-        dists = np.asarray(res.dists[:n])
-        leaves_np = np.asarray(leaves[:n])
         tr = get_tracer()
-        if tr.enabled:
-            t1 = tr.now()
-            tr.add_span(
-                "engine.execute", t1 - dt, t1, rows=n, bucket=rt.bucket,
-                layout=rt.plan.layout, segments=len(rt.plans),
-                plan=signature_key(plan_signature(rt.plan)),
-                cost_model=self.active_cost_model(),
-            )
-        if self._use_codes:
-            # the rung emitted rt.rerank ADC candidates per query; fetch
-            # the survivors' raw rows and rerank exactly (the rerank wall
-            # time is part of serving the request, so it stays in dt)
-            t_r = time.perf_counter()
-            with tr.span("engine.rerank", k=self.k,
-                         candidates=int(ids.shape[1])):
-                ids, dists = rerank_exact(
-                    self.index.read_rows, queries, ids, self.k
+        with tr.span("engine.execute") as span:
+            if tr.enabled:
+                span.set(
+                    rows=n, bucket=rt.bucket, layout=rt.plan.layout,
+                    segments=len(rt.plans),
+                    plan=signature_key(plan_signature(rt.plan)),
+                    cost_model=self.active_cost_model(),
                 )
-            dt += time.perf_counter() - t_r
+            with tr.span("session.pad"):
+                buf = np.zeros((rt.bucket, d), np.float32)
+                buf[:n] = queries
+            t0 = time.perf_counter()
+            with tr.span("session.dispatch"):
+                res, leaves = self._dispatch(rt, jnp.asarray(buf),
+                                             np.int32(n))
+            with tr.span("session.wait"):
+                jax.block_until_ready((res.ids, res.dists, leaves))
+            dt = time.perf_counter() - t0
+            with tr.span("session.fetch"):
+                ids = np.asarray(res.ids[:n])
+                dists = np.asarray(res.dists[:n])
+                leaves_np = np.asarray(leaves[:n])
+                overflow, pairs = jax.device_get(
+                    (res.q_cap_overflow, res.pairs)
+                )
+            if self._use_codes:
+                # the rung emitted rt.rerank ADC candidates per query;
+                # fetch the survivors' raw rows and rerank exactly (the
+                # rerank wall time is part of serving the request, so it
+                # stays in dt)
+                t_r = time.perf_counter()
+                with tr.span("engine.rerank", k=self.k,
+                             candidates=int(ids.shape[1])):
+                    ids, dists = rerank_exact(
+                        self.index.read_rows, queries, ids, self.k
+                    )
+                dt += time.perf_counter() - t_r
+            with tr.span("session.record"):
+                self._record(rt, queries, leaves_np, n, n_images, dt,
+                             overflow=int(overflow), pairs=float(pairs))
+        return ids, dists, leaves_np, dt
+
+    def _record(self, rt, queries, leaves_np, n, n_images, dt, *,
+                overflow: int, pairs: float) -> None:
+        """One dispatch's accounting: metrics, calibration, hot-leaf
+        cache."""
         self.metrics.engine_batches += 1
         self.metrics.engine_ms += dt * 1e3
         self.metrics.query_rows += n
-        overflow = int(res.q_cap_overflow)
         self.metrics.q_cap_overflow += overflow
+        useful = int(round(pairs))
+        self.metrics.pairs_useful += useful
+        self.metrics.pairs_computed += rt.pairs_computed
+        # process-wide totals: they outlive the session (the benchmark
+        # reads them after set-up objects are freed)
+        reg = get_registry()
+        reg.counter("engine.pairs_useful").inc(useful)
+        reg.counter("engine.pairs_computed").inc(rt.pairs_computed)
         if n_images:
             self.metrics.engine_images += n_images
             self._record_calibration(rt, dt * 1e3 / n_images)
@@ -714,7 +811,6 @@ class SearchSession:
             # would answer with an exact scan, diverging from the
             # ADC+rerank tier the engine serves.
             self.cache.record(queries, leaves_np, exact=overflow == 0)
-        return ids, dists, leaves_np, dt
 
     def search(
         self, queries: np.ndarray, *, n_images: int | None = None

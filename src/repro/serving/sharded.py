@@ -74,6 +74,7 @@ class _ShardedRuntime:
     plans: tuple  # every resolved per-segment plan across shards
     q_total: int  # largest per-segment padded lookup row count
     plan_rows: tuple = ()  # (plan, padded rows, n_shards) across shards
+    pairs_computed: int = 0  # over every shard's every segment
 
 
 class ShardedSearchSession(SearchSession):
@@ -209,6 +210,7 @@ class ShardedSearchSession(SearchSession):
                 plan_rows=tuple(
                     pr for _, _, rt in parts for pr in rt.plan_rows
                 ),
+                pairs_computed=sum(rt.pairs_computed for _, _, rt in parts),
             )
 
     def _global_rerank(self, shard_views, bucket: int) -> int | None:
@@ -325,14 +327,26 @@ class ShardedSearchSession(SearchSession):
         return dt_ms
 
     # -- serve path ----------------------------------------------------------
-    def _dispatch_shard(self, si, rt, views, buf, n_valid):
-        """Invoke one shard's fused pipeline (codes rungs take that
-        shard's device codes + the codebook table as extra args)."""
+    def _shard_args(self, si, rt, views, buf, n_valid) -> tuple:
+        """One shard's call arguments (codes rungs take that shard's
+        device codes + the codebook table as extra args)."""
         tree = self._shard_trees[si]
         if rt.rerank is not None:
-            return rt.fn(views, self._shard_codes[si],
-                         self._codebooks_dev, tree, buf, n_valid)
-        return rt.fn(views, tree, buf, n_valid)
+            return (views, self._shard_codes[si], self._codebooks_dev,
+                    tree, buf, n_valid)
+        return (views, tree, buf, n_valid)
+
+    def _dispatch_shard(self, si, rt, views, buf, n_valid):
+        """Invoke one shard's fused pipeline."""
+        return rt.fn(*self._shard_args(si, rt, views, buf, n_valid))
+
+    def _programs(self):
+        """Every (shard, bucket) program, on an empty batch."""
+        for b, rtb in self._runtimes.items():
+            dummy = jnp.zeros((b, self.index.dim), jnp.float32)
+            for si, views, rt in rtb.parts:
+                yield b, rt.fn, self._shard_args(si, rt, views, dummy,
+                                                 np.int32(0))
 
     def _execute(
         self, queries: np.ndarray, *, n_images: int | None = None
@@ -416,20 +430,12 @@ class ShardedSearchSession(SearchSession):
         # every shard routes the same queries through the same tree; shard
         # 0's probe-leaf matrix is THE routing (the broadcast analog)
         leaves_np = np.asarray(outs[0][1][:n])
-        overflow = sum(int(res.q_cap_overflow) for res, _, _ in outs)
-        self.metrics.engine_batches += 1
-        self.metrics.engine_ms += dt * 1e3
-        self.metrics.query_rows += n
-        self.metrics.q_cap_overflow += overflow
-        if n_images:
-            self.metrics.engine_images += n_images
-            self._record_calibration(rtb, dt * 1e3 / n_images)
-            # measured engine cost refines the cache's eviction score
-            self.cache.note_engine_cost(dt * 1e3 / n_images)
-        if not self._use_codes:
-            # a starved dispatch must not seed the cache (see
-            # SearchSession; codes sessions never seed it at all)
-            self.cache.record(queries, leaves_np, exact=overflow == 0)
+        counts = jax.device_get(
+            [(res.q_cap_overflow, res.pairs) for res, _, _ in outs]
+        )
+        self._record(rtb, queries, leaves_np, n, n_images, dt,
+                     overflow=sum(int(o) for o, _ in counts),
+                     pairs=sum(float(p) for _, p in counts))
         return ids, dists, leaves_np, dt
 
     # -- reporting ------------------------------------------------------------

@@ -166,6 +166,124 @@ def test_null_tracer_is_inert():
                                       "spans": 0, "events": 0, "dropped": 0}
 
 
+def test_tracer_span_opens_a_trace_annotation_only_when_enabled(
+        monkeypatch):
+    from repro.obs import tracer as tracer_mod
+
+    opened = []
+
+    class Recorder:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            opened.append(("open", self.name))
+
+        def __exit__(self, *exc):
+            opened.append(("close", self.name))
+            return False
+
+    monkeypatch.setattr(tracer_mod, "TraceAnnotation", Recorder)
+    with NULL_TRACER.span("off") as s:
+        assert s is NULL_SPAN
+    assert opened == []  # the disabled tracer builds none
+    tr = Tracer()
+    with tr.span("outer"):
+        with tr.span("inner"):
+            assert opened == [("open", "outer"), ("open", "inner")]
+    assert opened[2:] == [("close", "inner"), ("close", "outer")]
+    tr.add_span("virtual", 0.0, 1.0)  # explicit intervals are not profiled
+    tr.event("tick")
+    assert len(opened) == 4
+
+
+def test_tracer_spans_land_on_the_profilers_host_plane(tmp_path):
+    from jax.profiler import ProfileData
+
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    with tr.span("obs.probe"):
+        with tr.span("obs.probe.child"):
+            jnp.ones(8).block_until_ready()
+    jax.profiler.stop_trace()
+    (path,) = [os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+               for f in fs if f.endswith(".xplane.pb")]
+    events = {ev.name: (ev.start_ns, ev.duration_ns)
+              for plane in ProfileData.from_file(path).planes
+              if plane.name == "/host:CPU"
+              for line in plane.lines for ev in line.events}
+    assert {"obs.probe", "obs.probe.child"} <= set(events)
+    (p0, pd), (c0, cd) = events["obs.probe"], events["obs.probe.child"]
+    assert p0 <= c0 and c0 + cd <= p0 + pd  # nested on the profiler clock
+
+
+SESSION_CHILDREN = ["session.pad", "session.dispatch", "session.wait",
+                    "session.fetch", "session.record"]
+
+
+def test_execute_emits_one_span_tree_per_call(corpus, grown):
+    vecs_np, _, _ = corpus
+    s = SearchSession(grown, k=5, layout="point_major", probes=2,
+                      buckets=(32, 96))
+    s.warmup()
+    tracer = Tracer()
+    with tracing(tracer):
+        s.search(vecs_np[:20], n_images=2)
+        s.search(vecs_np[20:90], n_images=7)
+    execs = [sp for sp in tracer.spans if sp.name == "engine.execute"]
+    assert [sp.attrs["bucket"] for sp in execs] == [32, 96]
+    assert [sp.attrs["rows"] for sp in execs] == [20, 70]
+    for ex in execs:
+        assert ex.parent_id is None
+        kids = [sp for sp in tracer.spans if sp.parent_id == ex.span_id]
+        assert [k.name for k in kids] == SESSION_CHILDREN
+        for a, b in zip(kids, kids[1:]):
+            assert a.t1 <= b.t0  # in order, one after the other
+        assert ex.t0 <= kids[0].t0 and kids[-1].t1 <= ex.t1
+    assert len(tracer.spans) == 2 * (1 + len(SESSION_CHILDREN))
+
+
+def _brute_force_pairs(session, leaves):
+    """Same-leaf (index row, lookup row) pairs of one dispatch: every real
+    row of each probed leaf, counted by numpy over the index arrays."""
+    n_leaves = session.index.n_leaves
+    per_leaf = sum(
+        np.bincount(lf[(lf >= 0) & (lf < n_leaves)], minlength=n_leaves)
+        for lf in (np.asarray(v.leaves) for v in session._segments))
+    return int(per_leaf[np.asarray(leaves).reshape(-1)].sum())
+
+
+@pytest.mark.parametrize("layout,probes", [
+    ("point_major", 1), ("point_major", 2), ("query_routed", 1)])
+def test_pair_counters_match_brute_force(corpus, grown, layout, probes):
+    vecs_np, _, _ = corpus
+    s = SearchSession(grown, k=5, layout=layout, probes=probes,
+                      buckets=(96,))
+    s.warmup()
+    rng = np.random.default_rng(5)
+    useful = 0
+    for n in (13, 96):
+        q = vecs_np[rng.choice(N, n, replace=False)] + 0.25
+        _, _, leaves, _ = s._execute(q.astype(np.float32))
+        useful += _brute_force_pairs(s, leaves)
+    m = s.metrics
+    assert m.q_cap_overflow == 0
+    assert m.pairs_useful == useful > 0
+    rt = s._runtimes[96]
+    assert m.pairs_computed == 2 * rt.pairs_computed > useful
+    if layout == "point_major":
+        # every cell of each segment's sweep: its rows against a q_cap slab
+        assert rt.pairs_computed == sum(
+            int(v.rows) * p.q_cap for p, v in zip(rt.plans, s._segments))
+    # the process-wide registry carries the same totals
+    counters = obs.get_registry().snapshot()["metrics"]
+    assert counters["engine.pairs_useful"] == m.pairs_useful
+    assert counters["engine.pairs_computed"] == m.pairs_computed
+    d = m.to_dict()
+    assert list(d)[-2:] == ["pairs_useful", "pairs_computed"]
+    assert d["pairs_useful"] == useful
+
+
 def test_sampling_is_deterministic_given_seed():
     rids = range(400)
     a = Tracer(sample=0.35, seed=7)
