@@ -1,10 +1,13 @@
 """The two search executors, built on the shared tile-scan core.
 
 Point-major (paper §2.4): every shard sweeps its cluster-sorted index rows
-in waves of ``block_rows`` against the replicated lookup table; the slab of
+in waves of ``block_rows`` against the replicated lookup table, visiting
+only the tiles whose leaf span meets a lookup row
+(``tilescan.live_waves``; the others could only fold ``inf``); the slab of
 queries colliding with a tile is contiguous (both sides leaf-sorted), and a
 running ``(rows, k)`` best table is folded per wave, then merged across
-shards with one log-shaped top-k.
+shards with one log-shaped top-k. The codes sweep skips tiles the same
+way.
 
 Query-routed (beyond-paper): the lookup rows are shuffled to the shard
 owning their leaf (the same capacity-padded counting sort + all_to_all as
@@ -58,9 +61,13 @@ class SearchResult:
     dists: jax.Array  # (Q, k) true squared L2 distances (inf where id=-1)
     pairs: jax.Array  # () number of (point, query) distance pairs computed
     q_cap_overflow: jax.Array  # () slab-budget misses (0 == exact-in-cluster)
+    # () tiles the executor scanned, summed over shards (wave_shape); a
+    # session rung's merged result holds one entry per segment
+    waves: jax.Array | None = None
 
     def tree_flatten(self):
-        return (self.ids, self.dists, self.pairs, self.q_cap_overflow), None
+        return (self.ids, self.dists, self.pairs, self.q_cap_overflow,
+                self.waves), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -155,7 +162,8 @@ def _shard_id(mesh: Mesh, axes) -> jax.Array:
 
 @jax.named_scope(phases.MERGE)
 def _merge_shard_tables(mesh, axes, plan, lookup, best_d, best_i, pairs,
-                        overflow, *, q_total, n_shards, width, add_q_norms):
+                        overflow, waves, *, q_total, n_shards, width,
+                        add_q_norms):
     """Merge per-shard ``(S, Q, width)`` k-NN tables into a SearchResult.
 
     (S, Q, w) sharded over S -> (Q, S*w) sharded over Q (all_to_all
@@ -183,7 +191,7 @@ def _merge_shard_tables(mesh, axes, plan, lookup, best_d, best_i, pairs,
     out_d = jax.lax.with_sharding_constraint(out_d, row_sh)
     out_i = jax.lax.with_sharding_constraint(out_i, row_sh)
     return SearchResult(ids=out_i, dists=out_d, pairs=pairs,
-                        q_cap_overflow=overflow)
+                        q_cap_overflow=overflow, waves=waves)
 
 
 def _point_major_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows, q_total,
@@ -196,14 +204,17 @@ def _point_major_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows, q_total,
         raise ValueError(f"{k=} must be <= {block_rows=}")
     if q_cap > q_total:
         raise ValueError(f"{q_cap=} must be <= padded query count {q_total=}")
-    n_waves = shard_rows // block_rows
 
     def shard_fn(vecs, leaves, ids, lk_vecs, lk_leaves, lk_offsets):
         vecs, leaves, ids = vecs[0], leaves[0], ids[0]
+        # only the tiles whose leaf span meets a lookup row can pair
+        waves, n_live = tilescan.live_waves(
+            leaves, lk_offsets, block_rows=block_rows, n_entries=n_leaves,
+        )
 
         def wave(i, c: _Carry) -> _Carry:
             with jax.named_scope(phases.SLICE):
-                start = i * block_rows
+                start = waves[i] * block_rows
                 pv = jax.lax.dynamic_slice(
                     vecs, (start, 0), (block_rows, vecs.shape[1])
                 )
@@ -243,11 +254,13 @@ def _point_major_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows, q_total,
             )
             # the carry varies across shards (each shard scans its own rows)
             init = jax.tree.map(lambda x: pcast_varying(x, axes), init)
-        out = jax.lax.fori_loop(0, n_waves, wave, init)
+        # a trip count of its own per shard: no collective in the loop
+        out = jax.lax.fori_loop(0, n_live, wave, init)
         with jax.named_scope(phases.COUNT):
             pairs = jax.lax.psum(out.pairs, axes)
             overflow = jax.lax.psum(out.overflow, axes)
-        return out.best_d[None], out.best_i[None], pairs, overflow
+            scanned = jax.lax.psum(n_live, axes)
+        return out.best_d[None], out.best_i[None], pairs, overflow, scanned
 
     def pipeline(index: DistributedIndex, lookup: LookupTable) -> SearchResult:
         d = index.vecs.shape[-1]
@@ -257,15 +270,17 @@ def _point_major_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows, q_total,
         row_spec = P(axes, None)
         flat_spec = P(axes)
         rep = P()
-        best_d, best_i, pairs, overflow = jax.shard_map(
+        best_d, best_i, pairs, overflow, scanned = jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(row_spec, flat_spec, flat_spec, rep, rep, rep),
-            out_specs=(P(axes, None, None), P(axes, None, None), rep, rep),
+            out_specs=(P(axes, None, None), P(axes, None, None), rep, rep,
+                       rep),
         )(vecs, leaves, ids, lookup.vecs, lookup.leaves, lookup.offsets)
         return _merge_shard_tables(
             mesh, axes, plan, lookup, best_d, best_i, pairs, overflow,
-            q_total=q_total, n_shards=n_shards, width=k, add_q_norms=True,
+            scanned, q_total=q_total, n_shards=n_shards, width=k,
+            add_q_norms=True,
         )
 
     return pipeline
@@ -405,7 +420,8 @@ def _query_routed_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows, q_total,
             out_d = jax.lax.with_sharding_constraint(out_d, row_sh)
             out_i = jax.lax.with_sharding_constraint(out_i, row_sh)
         return SearchResult(ids=out_i, dists=out_d, pairs=pairs,
-                            q_cap_overflow=overflow)
+                            q_cap_overflow=overflow,
+                            waves=jnp.int32(n_qwaves * n_shards))
 
     return pipeline
 
@@ -450,15 +466,17 @@ def _scan_codes_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows, q_total,
         raise ValueError(f"rerank {r} must be <= {block_rows=}")
     if q_cap > q_total:
         raise ValueError(f"{q_cap=} must be <= padded query count {q_total=}")
-    n_waves = shard_rows // block_rows
     from repro.core.sentinels import PAD_TILE_POINT_LEAF
 
     def shard_fn(codes, leaves, ids, lk_lut, lk_leaves, lk_offsets):
         codes, leaves, ids = codes[0], leaves[0], ids[0]
+        waves, n_live = tilescan.live_waves(
+            leaves, lk_offsets, block_rows=block_rows, n_entries=n_leaves,
+        )
 
         def wave(i, c: _Carry) -> _Carry:
             with jax.named_scope(phases.SLICE):
-                start = i * block_rows
+                start = waves[i] * block_rows
                 pc = jax.lax.dynamic_slice(codes, (start, 0), (block_rows, m))
                 plf = jax.lax.dynamic_slice(leaves, (start,), (block_rows,))
                 pid = jax.lax.dynamic_slice(ids, (start,), (block_rows,))
@@ -502,11 +520,12 @@ def _scan_codes_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows, q_total,
                 overflow=jnp.zeros((), jnp.int32),
             )
             init = jax.tree.map(lambda x: pcast_varying(x, axes), init)
-        out = jax.lax.fori_loop(0, n_waves, wave, init)
+        out = jax.lax.fori_loop(0, n_live, wave, init)
         with jax.named_scope(phases.COUNT):
             pairs = jax.lax.psum(out.pairs, axes)
             overflow = jax.lax.psum(out.overflow, axes)
-        return out.best_d[None], out.best_i[None], pairs, overflow
+            scanned = jax.lax.psum(n_live, axes)
+        return out.best_d[None], out.best_i[None], pairs, overflow, scanned
 
     def pipeline(index: DistributedIndex, lookup: LookupTable,
                  codes: jax.Array, codebooks: jax.Array) -> SearchResult:
@@ -519,17 +538,19 @@ def _scan_codes_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows, q_total,
         row_spec = P(axes, None)
         flat_spec = P(axes)
         rep = P()
-        best_d, best_i, pairs, overflow = jax.shard_map(
+        best_d, best_i, pairs, overflow, scanned = jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(row_spec, flat_spec, flat_spec, rep, rep, rep),
-            out_specs=(P(axes, None, None), P(axes, None, None), rep, rep),
+            out_specs=(P(axes, None, None), P(axes, None, None), rep, rep,
+                       rep),
         )(codes3, leaves, ids, lut, lookup.leaves, lookup.offsets)
         # ADC distances are *full* squared estimates (the LUT carries the
         # ||q_j - c||^2 terms), so unlike the dense scan no ||q||^2 add-back
         return _merge_shard_tables(
             mesh, axes, plan, lookup, best_d, best_i, pairs, overflow,
-            q_total=q_total, n_shards=n_shards, width=r, add_q_norms=False,
+            scanned, q_total=q_total, n_shards=n_shards, width=r,
+            add_q_norms=False,
         )
 
     return pipeline
@@ -654,7 +675,8 @@ def _point_major_fused_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows,
         )(vecs, leaves, ids, lookup.vecs, lookup.leaves, lookup.offsets)
         return _merge_shard_tables(
             mesh, axes, plan, lookup, best_d, best_i, pairs, overflow,
-            q_total=q_total, n_shards=n_shards, width=k, add_q_norms=True,
+            jnp.int32(n_waves * n_shards), q_total=q_total,
+            n_shards=n_shards, width=k, add_q_norms=True,
         )
 
     return pipeline
@@ -786,7 +808,8 @@ def _scan_codes_fused_fn(mesh, plan: SearchPlan, *, n_leaves, shard_rows,
         )(codes3, leaves, ids, lut, lookup.leaves, lookup.offsets)
         return _merge_shard_tables(
             mesh, axes, plan, lookup, best_d, best_i, pairs, overflow,
-            q_total=q_total, n_shards=n_shards, width=r, add_q_norms=False,
+            jnp.int32(n_waves * n_shards), q_total=q_total,
+            n_shards=n_shards, width=r, add_q_norms=False,
         )
 
     return pipeline
@@ -843,24 +866,30 @@ def make_executor(
     )
 
 
-def pairs_computed(plan: SearchPlan, *, shard_rows: int, q_total: int,
-                   n_shards: int) -> int:
-    """Distance pairs one run of ``plan``'s executor evaluates, useful or
-    not: every (point row, query row) cell of the tiles it scans, summed
-    over shards. ``SearchResult.pairs`` counts the same-leaf cells among
-    them, so the two give the scan's pair yield.
+def wave_shape(plan: SearchPlan, *, shard_rows: int, q_total: int,
+               n_shards: int) -> tuple[int, int]:
+    """``(waves, pairs per wave)`` of one run of ``plan``'s executor: the
+    tiles it would scan with none skipped, summed over shards, and the
+    distance pairs each evaluates, useful or not. ``SearchResult.waves``
+    counts the tiles it did scan, so ``waves * pairs per wave`` is the
+    run's computed pairs; ``SearchResult.pairs`` counts the same-leaf
+    cells among them, so the two give the scan's pair yield.
 
-    Point-major and codes sweeps visit every ``block_rows`` wave against a
-    ``q_cap`` query slab (``shard_rows * q_cap``); query-routed visits every
-    ``q_tile`` of its routed query rows against a ``p_cap`` point slab; the
-    whole-shard fused kernel meets every point row with every lookup row
-    (before tile padding).
+    Point-major and codes sweeps tile the shard by ``block_rows`` against a
+    ``q_cap`` query slab and skip the tiles no lookup row meets
+    (``tilescan.live_waves``); query-routed visits every ``q_tile`` of its
+    routed query rows against a ``p_cap`` point slab; the whole-shard fused
+    kernel meets every point row with every lookup row (before tile
+    padding).
     """
     plan = plan.resolved()
     if plan.layout == "query_routed":
-        per_shard = _routed_query_cap(plan, q_total, n_shards) * plan.p_cap
+        per_shard = _routed_query_cap(plan, q_total, n_shards) // plan.q_tile
+        per_wave = plan.q_tile * plan.p_cap
     elif plan.impl == "fused" and _fused_wants_kernel():
-        per_shard = shard_rows * q_total
+        per_shard = shard_rows // plan.block_rows
+        per_wave = plan.block_rows * q_total
     else:
-        per_shard = shard_rows * plan.q_cap
-    return int(per_shard) * int(n_shards)
+        per_shard = shard_rows // plan.block_rows
+        per_wave = plan.block_rows * plan.q_cap
+    return int(per_shard) * int(n_shards), int(per_wave)

@@ -69,6 +69,34 @@ def last_valid_leaf(leaves: jax.Array, *, base=0) -> jax.Array:
     return jnp.max(jnp.where(valid, leaves - base, -1))
 
 
+@jax.named_scope(phases.SLICE)
+def live_waves(
+    leaves: jax.Array, offsets: jax.Array, *, block_rows: int,
+    n_entries: int
+) -> tuple[jax.Array, jax.Array]:
+    """The point tiles a point-major sweep has to visit, in sweep order.
+
+    ``leaves`` is a shard's cluster-sorted leaf column, cut into
+    ``block_rows``-row tiles; ``offsets`` the lookup table's CSR offsets
+    (``n_entries + 1``). A tile is *live* when some lookup row falls in its
+    leaf span ``[leaves[t * block_rows], last valid leaf]``; any other tile
+    (all padding, or leaves no query routed to) has no same-leaf pair, no
+    slab overflow and only ``inf`` candidates, so skipping it leaves every
+    result bit-identical.
+
+    Returns ``(waves, n_live)``: the live tile indices, ascending, at the
+    front of an ``(n_tiles,)`` int32 array, and how many there are.
+    """
+    tiles = leaves.reshape(-1, block_rows)
+    first = jnp.clip(tiles[:, 0], 0, n_entries - 1)
+    # the highest real leaf of each tile, -1 if none (last_valid_leaf)
+    last = jnp.max(jnp.where(tiles != LEAF_SENTINEL, tiles, -1), axis=1)
+    hits = offsets[jnp.clip(last, 0, n_entries - 1) + 1] - offsets[first]
+    live = (last >= 0) & (hits > 0)
+    (waves,) = jnp.nonzero(live, size=tiles.shape[0], fill_value=0)
+    return waves.astype(jnp.int32), jnp.sum(live, dtype=jnp.int32)
+
+
 def scan_tile(
     pv: jax.Array,
     plf: jax.Array,

@@ -100,6 +100,7 @@ def search_with_lookup(
         dists=res.dists[:n_queries],
         pairs=res.pairs,
         q_cap_overflow=res.q_cap_overflow,
+        waves=res.waves,
     )
 
 

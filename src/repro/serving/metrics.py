@@ -196,6 +196,10 @@ class ServingMetrics:
     # and the pairs their tiles evaluated: the scan's pair yield
     pairs_useful: int = 0
     pairs_computed: int = 0
+    # tiles the scans visited, and those they would have visited with no
+    # tile skipped (executors.wave_shape)
+    waves_scanned: int = 0
+    waves_total: int = 0
 
     def __post_init__(self):
         if self.max_samples is not None:
@@ -319,6 +323,8 @@ class ServingMetrics:
             out[f"serving.class.latency.hist{lbl}"] = cm.latency.histogram()
         out["serving.pairs_useful"] = self.pairs_useful
         out["serving.pairs_computed"] = self.pairs_computed
+        out["serving.waves_scanned"] = self.waves_scanned
+        out["serving.waves_total"] = self.waves_total
         return out
 
     def to_dict(self) -> dict:
@@ -351,4 +357,6 @@ class ServingMetrics:
             },
             "pairs_useful": self.pairs_useful,
             "pairs_computed": self.pairs_computed,
+            "waves_scanned": self.waves_scanned,
+            "waves_total": self.waves_total,
         }

@@ -55,7 +55,7 @@ from repro.core import phases
 from repro.core.engine.executors import (
     SearchResult,
     pad_lookup,
-    pairs_computed,
+    wave_shape,
 )
 from repro.core.index_build import DistributedIndex
 from repro.core.lookup import build_lookup_bucketed
@@ -88,9 +88,11 @@ class _BucketRuntime:
     # emits (the caller reranks exactly), and the fused fn's signature
     # grows to (segments, codes, codebooks, tree, queries, n_valid)
     rerank: int | None = None
-    # distance pairs one dispatch evaluates over every segment's tiles,
-    # useful or not (executors.pairs_computed)
-    pairs_computed: int = 0
+    # tiles one dispatch would scan with none skipped, over every segment
+    # and shard, and per segment the distance pairs one scanned tile
+    # evaluates, useful or not (executors.wave_shape)
+    waves_total: int = 0
+    pairs_per_wave: tuple = ()
 
 
 def make_bucket_runtime(
@@ -201,8 +203,10 @@ def make_bucket_runtime(
 
     @jax.named_scope(phases.MERGE)
     def merge(outs, leaves):
+        # each segment's scanned tiles, for the host's pair accounting
+        waves = jnp.stack([r_.waves for r_ in outs])
         if len(outs) == 1 and not emit_slots:
-            return outs[0], leaves
+            return dataclasses.replace(outs[0], waves=waves), leaves
         all_d = jnp.concatenate([r_.dists[:bucket] for r_ in outs], axis=1)
         all_i = jnp.concatenate([r_.ids[:bucket] for r_ in outs], axis=1)
         pairs = sum(r_.pairs for r_ in outs)
@@ -215,6 +219,7 @@ def make_bucket_runtime(
                 dists=jnp.take_along_axis(all_d, sel, axis=1),
                 pairs=pairs,
                 q_cap_overflow=overflow,
+                waves=waves,
             )
             return merged, leaves, slot_cols[sel]
         # cross-segment merge: same ascending-distance fold the
@@ -225,6 +230,7 @@ def make_bucket_runtime(
             dists=-neg,
             pairs=pairs,
             q_cap_overflow=overflow,
+            waves=waves,
         )
         return merged, leaves
 
@@ -251,6 +257,11 @@ def make_bucket_runtime(
             ]
             return merge(outs, leaves)
 
+    shapes = [
+        wave_shape(p, shard_rows=v.rows // n_shards, q_total=qt,
+                   n_shards=n_shards)
+        for p, v, qt in zip(plans, segments, q_totals)
+    ]
     return _BucketRuntime(
         bucket=bucket, plan=plans[primary], plans=tuple(plans),
         q_total=max(q_totals), fn=jax.jit(fused),
@@ -262,11 +273,8 @@ def make_bucket_runtime(
             for bp, v in zip(base_plans, segments)
         ),
         rerank=r if use_codes else None,
-        pairs_computed=sum(
-            pairs_computed(p, shard_rows=v.rows // n_shards, q_total=qt,
-                           n_shards=n_shards)
-            for p, v, qt in zip(plans, segments, q_totals)
-        ),
+        waves_total=sum(w for w, _ in shapes),
+        pairs_per_wave=tuple(pw for _, pw in shapes),
     )
 
 
@@ -763,8 +771,8 @@ class SearchSession:
                 ids = np.asarray(res.ids[:n])
                 dists = np.asarray(res.dists[:n])
                 leaves_np = np.asarray(leaves[:n])
-                overflow, pairs = jax.device_get(
-                    (res.q_cap_overflow, res.pairs)
+                overflow, pairs, waves = jax.device_get(
+                    (res.q_cap_overflow, res.pairs, res.waves)
                 )
             if self._use_codes:
                 # the rung emitted rt.rerank ADC candidates per query;
@@ -780,25 +788,34 @@ class SearchSession:
                 dt += time.perf_counter() - t_r
             with tr.span("session.record"):
                 self._record(rt, queries, leaves_np, n, n_images, dt,
-                             overflow=int(overflow), pairs=float(pairs))
+                             overflow=int(overflow), pairs=float(pairs),
+                             waves=waves)
         return ids, dists, leaves_np, dt
 
     def _record(self, rt, queries, leaves_np, n, n_images, dt, *,
-                overflow: int, pairs: float) -> None:
+                overflow: int, pairs: float, waves) -> None:
         """One dispatch's accounting: metrics, calibration, hot-leaf
-        cache."""
+        cache. ``waves`` holds the tiles each segment's scan visited, in
+        ``rt.pairs_per_wave`` order."""
         self.metrics.engine_batches += 1
         self.metrics.engine_ms += dt * 1e3
         self.metrics.query_rows += n
         self.metrics.q_cap_overflow += overflow
         useful = int(round(pairs))
+        waves = [int(w) for w in np.ravel(waves)]
+        scanned = sum(waves)
+        computed = sum(w * pw for w, pw in zip(waves, rt.pairs_per_wave))
         self.metrics.pairs_useful += useful
-        self.metrics.pairs_computed += rt.pairs_computed
+        self.metrics.pairs_computed += computed
+        self.metrics.waves_scanned += scanned
+        self.metrics.waves_total += rt.waves_total
         # process-wide totals: they outlive the session (the benchmark
         # reads them after set-up objects are freed)
         reg = get_registry()
         reg.counter("engine.pairs_useful").inc(useful)
-        reg.counter("engine.pairs_computed").inc(rt.pairs_computed)
+        reg.counter("engine.pairs_computed").inc(computed)
+        reg.counter("engine.waves_scanned").inc(scanned)
+        reg.counter("engine.waves_total").inc(rt.waves_total)
         if n_images:
             self.metrics.engine_images += n_images
             self._record_calibration(rt, dt * 1e3 / n_images)

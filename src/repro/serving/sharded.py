@@ -74,7 +74,9 @@ class _ShardedRuntime:
     plans: tuple  # every resolved per-segment plan across shards
     q_total: int  # largest per-segment padded lookup row count
     plan_rows: tuple = ()  # (plan, padded rows, n_shards) across shards
-    pairs_computed: int = 0  # over every shard's every segment
+    # over every shard's every segment, in parts order (_BucketRuntime)
+    waves_total: int = 0
+    pairs_per_wave: tuple = ()
 
 
 class ShardedSearchSession(SearchSession):
@@ -210,7 +212,10 @@ class ShardedSearchSession(SearchSession):
                 plan_rows=tuple(
                     pr for _, _, rt in parts for pr in rt.plan_rows
                 ),
-                pairs_computed=sum(rt.pairs_computed for _, _, rt in parts),
+                waves_total=sum(rt.waves_total for _, _, rt in parts),
+                pairs_per_wave=tuple(
+                    pw for _, _, rt in parts for pw in rt.pairs_per_wave
+                ),
             )
 
     def _global_rerank(self, shard_views, bucket: int) -> int | None:
@@ -431,11 +436,13 @@ class ShardedSearchSession(SearchSession):
         # 0's probe-leaf matrix is THE routing (the broadcast analog)
         leaves_np = np.asarray(outs[0][1][:n])
         counts = jax.device_get(
-            [(res.q_cap_overflow, res.pairs) for res, _, _ in outs]
+            [(res.q_cap_overflow, res.pairs, res.waves) for res, _, _ in outs]
         )
         self._record(rtb, queries, leaves_np, n, n_images, dt,
-                     overflow=sum(int(o) for o, _ in counts),
-                     pairs=sum(float(p) for _, p in counts))
+                     overflow=sum(int(o) for o, _, _ in counts),
+                     pairs=sum(float(p) for _, p, _ in counts),
+                     waves=np.concatenate(
+                         [np.ravel(w) for _, _, w in counts]))
         return ids, dists, leaves_np, dt
 
     # -- reporting ------------------------------------------------------------

@@ -260,6 +260,15 @@ def test_pair_counters_match_brute_force(corpus, grown, layout, probes):
     s = SearchSession(grown, k=5, layout=layout, probes=probes,
                       buckets=(96,))
     s.warmup()
+    # each dispatch's tiles scanned per segment, as the session records them
+    seen = []
+    record = s._record
+
+    def spy(*args, **kw):
+        seen.append(np.ravel(kw["waves"]).astype(int))
+        return record(*args, **kw)
+
+    s._record = spy
     rng = np.random.default_rng(5)
     useful = 0
     for n in (13, 96):
@@ -270,18 +279,37 @@ def test_pair_counters_match_brute_force(corpus, grown, layout, probes):
     assert m.q_cap_overflow == 0
     assert m.pairs_useful == useful > 0
     rt = s._runtimes[96]
-    assert m.pairs_computed == 2 * rt.pairs_computed > useful
+    assert m.waves_total == 2 * rt.waves_total
+    assert m.waves_scanned == sum(int(w.sum()) for w in seen)
+    assert 0 < m.waves_scanned <= m.waves_total
     if layout == "point_major":
-        # every cell of each segment's sweep: its rows against a q_cap slab
-        assert rt.pairs_computed == sum(
-            int(v.rows) * p.q_cap for p, v in zip(rt.plans, s._segments))
+        # each segment's sweep tiles its rows by block_rows against a q_cap
+        # slab; only the tiles a lookup row meets are scanned
+        assert rt.waves_total == sum(
+            int(v.rows) // p.block_rows for p, v in zip(rt.plans, s._segments))
+        for w in seen:
+            assert all(0 <= wi <= int(v.rows) // p.block_rows
+                       for wi, p, v in zip(w, rt.plans, s._segments))
+        assert m.pairs_computed == sum(
+            int(wi) * p.block_rows * p.q_cap
+            for w in seen for wi, p in zip(w, rt.plans)) > useful
+    else:
+        # query-routed scans every routed query tile
+        assert m.waves_scanned == m.waves_total
+        assert m.pairs_computed == 2 * sum(
+            w * pw for w, pw in zip(seen[0], rt.pairs_per_wave)) > useful
     # the process-wide registry carries the same totals
     counters = obs.get_registry().snapshot()["metrics"]
     assert counters["engine.pairs_useful"] == m.pairs_useful
     assert counters["engine.pairs_computed"] == m.pairs_computed
+    assert counters["engine.waves_scanned"] == m.waves_scanned
+    assert counters["engine.waves_total"] == m.waves_total
     d = m.to_dict()
-    assert list(d)[-2:] == ["pairs_useful", "pairs_computed"]
+    assert list(d)[-4:] == ["pairs_useful", "pairs_computed",
+                            "waves_scanned", "waves_total"]
     assert d["pairs_useful"] == useful
+    assert d["waves_scanned"] == m.waves_scanned
+    assert d["waves_total"] == m.waves_total
 
 
 def test_sampling_is_deterministic_given_seed():
