@@ -70,13 +70,3 @@ def test_a_cell_is_added_by_files_and_entries_alone(tmp_path):
     # no TPU plane on the CPU: the trace readers read nothing
     assert list(result["metrics"]) == ["calls.throwaway"]
     assert result["metrics"]["calls.throwaway"]["value"] >= 1
-
-
-def test_traced_cell_reads_the_scan_counters(root):
-    cell = "copydays-sift.batch"
-    result, lines = tiny.measure(root, cell, trace=True)
-    assert result["correct"], lines
-    metrics = result["metrics"]
-    for name in ("scan_wave_share_pct.batch", "scan_pair_yield_pct.batch"):
-        assert metrics[name]["unit"] == "%"
-        assert 0 < metrics[name]["value"] <= 100
